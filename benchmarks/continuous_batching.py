@@ -361,17 +361,20 @@ def run_capacity_sweep(model, params, n_req: int, seed: int,
 
 
 # ---------------------------------------------------------------------------
-# Tensor-parallel strong scaling (--mesh): 1 -> 8 host devices
+# Tensor-parallel strong scaling (--mesh): a CPU-only rehearsal over 1 -> 8
+# virtual host devices.  Each TP degree runs in a child process pinned to
+# the CPU, so the sweep never competes for (or reports from) an
+# accelerator; tensor-parallel serving on a chip is `chip_smoke.py --mesh`.
 # ---------------------------------------------------------------------------
 
 _MESH_WORKER = """
 import os, json, sys, time, dataclasses
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(tp)d"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax, jax.numpy as jnp, numpy as np
 sys.path.insert(0, %(root)r)
 from benchmarks.continuous_batching import (BENCH_CONFIG, MAX_NEW, PAGE,
                                             PROMPT_LEN, make_trace)
+from repro.launch.mesh import make_mesh
 from repro.models.model import build_model
 from repro.runtime.engine import ContinuousServeEngine
 from repro.runtime.sampling import SamplingParams
@@ -384,7 +387,7 @@ model = build_model(cfg)
 params = jax.tree.map(
     lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
     model.init(jax.random.PRNGKey(seed)))
-mesh = jax.make_mesh((1, tp), ("data", "model")) if tp > 1 else None
+mesh = make_mesh((1, tp), ("data", "model")) if tp > 1 else None
 eng = ContinuousServeEngine(
     model, params, num_slots=batch, page_size=PAGE,
     num_pages=1 + 2 * batch * -(-(PROMPT_LEN + MAX_NEW) // PAGE),
@@ -413,12 +416,13 @@ print(json.dumps({
 
 def run_mesh_sweep(n_req: int, batch: int, seed: int,
                    tps=(1, 2, 4, 8)) -> list[Row]:
-    """Strong-scaling sweep over the TP degree, one subprocess per point
-    (each needs its own XLA host-device count and a clean compile cache).
-    CPU host devices share one socket, so tokens/s is a smoke signal here;
-    the architectural observables are per-device KV bytes/token (must
-    shrink 1/TP — the paper's add-bandwidth-by-adding-CUs lever) and the
-    per-step collective bytes the Megatron pairing costs."""
+    """CPU strong-scaling rehearsal over the TP degree, one CPU-pinned
+    subprocess per point (each needs its own XLA host-device count and a
+    clean compile cache).  CPU host devices share one socket, so tokens/s
+    is a CPU smoke signal, not a device number; the architectural
+    observables are per-device KV bytes/token (must shrink 1/TP — the
+    paper's add-bandwidth-by-adding-CUs lever) and the per-step collective
+    bytes the Megatron pairing costs."""
     import pathlib
     import subprocess
     import sys
@@ -430,7 +434,7 @@ def run_mesh_sweep(n_req: int, batch: int, seed: int,
                                "seed": seed, "root": root}
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                            text=True, timeout=1200,
-                           env={**os.environ,
+                           env={**os.environ, "JAX_PLATFORMS": "cpu",
                                 "PYTHONPATH": os.path.join(root, "src")})
         assert r.returncode == 0, r.stderr[-3000:]
         results.append(json.loads(r.stdout.strip().splitlines()[-1]))
@@ -440,7 +444,7 @@ def run_mesh_sweep(n_req: int, batch: int, seed: int,
         tp = res["tp"]
         ratio = base["kv_bytes_per_token_per_device"] \
             / res["kv_bytes_per_token_per_device"]
-        rows.append(Row("ours:tp-serving", f"tp={tp} useful tok/s",
+        rows.append(Row("ours:tp-serving", f"tp={tp} CPU useful tok/s",
                         res["tokens_per_s"], None, "",
                         f"{res['steps']} steps, reduce={res['reduce']}"))
         rows.append(Row("ours:tp-serving", f"tp={tp} KV bytes/token/device",
@@ -510,10 +514,11 @@ def main(argv=None) -> int:
     ap.add_argument("--skip-throughput", action="store_true",
                     help="run only the shared-prefix workload (faster)")
     ap.add_argument("--mesh", action="store_true",
-                    help="tensor-parallel strong-scaling sweep instead: "
-                         "1 -> 8 host devices, one subprocess per TP "
-                         "degree (tokens/s, per-device KV bytes/token, "
-                         "per-step collective bytes)")
+                    help="CPU-only tensor-parallel rehearsal instead: "
+                         "1 -> 8 virtual CPU devices, one CPU-pinned "
+                         "subprocess per TP degree (CPU tokens/s, "
+                         "per-device KV bytes/token, per-step collective "
+                         "bytes)")
     ap.add_argument("--capacity-sweep", action="store_true",
                     help="DeploymentSpec capacity sweep instead: serve the "
                          "same trace under fixed-bandwidth HBM-CO stacks "

@@ -23,9 +23,20 @@ def gqa_decode_attention(q, k_cache, v_cache, cur_len, *, block_s: int = 512):
                             interpret=on_cpu())
 
 
+def _oracle_pools(layer, d, k_pages, v_pages, k_scales, v_scales):
+    """The oracles' view of serve-layout pools: one layer (of layer-stacked
+    pools) with the lane-dense ``KVH * D`` axis split into ``(KVH, D)``."""
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
+        if k_scales is not None:
+            k_scales, v_scales = k_scales[layer], v_scales[layer]
+    split = lambda p: p.reshape(p.shape[:-1] + (p.shape[-1] // d, d))
+    return split(k_pages), split(v_pages), k_scales, v_scales
+
+
 def paged_gqa_multi_attention(q, k_pages, v_pages, page_table, start, *,
-                              k_scales=None, v_scales=None, causal=True,
-                              window=None, impl: str = "auto"):
+                              layer=None, k_scales=None, v_scales=None,
+                              causal=True, window=None, impl: str = "auto"):
     """Multi-token paged attention: the q_len > 1 counterpart of
     ``paged_gqa_decode_attention``, used by chunked prefill and the
     speculative verify step (q_len = gamma + 1).
@@ -33,8 +44,8 @@ def paged_gqa_multi_attention(q, k_pages, v_pages, page_table, start, *,
     q:          (B, C, H, D) — C queries per slot at per-row absolute
                 offsets ``start`` (query j of row b sits at position
                 start[b] + j and attends causally up to itself)
-    k_pages / v_pages / page_table / k_scales / v_scales: as in
-                ``paged_gqa_decode_attention``
+    k_pages / v_pages / page_table / layer / k_scales / v_scales: as in
+                ``paged_gqa_decode_attention`` (serve-layout pools)
 
     Impls (no separate kernel either way — the gather-fused Pallas path
     only covers q_len == 1 today; multi-token flash-decode over
@@ -57,6 +68,8 @@ def paged_gqa_multi_attention(q, k_pages, v_pages, page_table, start, *,
         impl = "reference" if on_cpu() else "blocked"
     if impl == "reference":
         assert causal, "the multi-token decode oracle is causal-only"
+        k_pages, v_pages, k_scales, v_scales = _oracle_pools(
+            layer, q.shape[-1], k_pages, v_pages, k_scales, v_scales)
         return paged_decode_multi_attention_ref(
             q, k_pages, v_pages, page_table, start, k_scales=k_scales,
             v_scales=v_scales, window=window)
@@ -64,12 +77,15 @@ def paged_gqa_multi_attention(q, k_pages, v_pages, page_table, start, *,
         raise ValueError(f"impl={impl!r} (want 'auto', 'blocked' or "
                          "'reference')")
     from repro.quant import kv as kvq
-    k_d = gather_pages(k_pages, page_table)
-    v_d = gather_pages(v_pages, page_table)
+    d = q.shape[-1]
+    k_d = gather_pages(k_pages, page_table, layer)       # (B, S, KVH * D)
+    v_d = gather_pages(v_pages, page_table, layer)
+    k_d = k_d.reshape(k_d.shape[:2] + (-1, d))
+    v_d = v_d.reshape(v_d.shape[:2] + (-1, d))
     if k_scales is not None:
-        k_d = kvq.kv_dequantize(k_d, gather_pages(k_scales, page_table),
+        k_d = kvq.kv_dequantize(k_d, gather_pages(k_scales, page_table, layer),
                                 q.dtype)
-        v_d = kvq.kv_dequantize(v_d, gather_pages(v_scales, page_table),
+        v_d = kvq.kv_dequantize(v_d, gather_pages(v_scales, page_table, layer),
                                 q.dtype)
     from repro.models.common import blocked_attention
     return blocked_attention(q, k_d, v_d, causal=causal, window=window,
@@ -77,9 +93,16 @@ def paged_gqa_multi_attention(q, k_pages, v_pages, page_table, start, *,
 
 
 def paged_gqa_decode_attention(q, k_pages, v_pages, page_table, pos, *,
-                               k_scales=None, v_scales=None,
+                               layer=None, k_scales=None, v_scales=None,
                                window=None, impl: str = "auto"):
-    """Paged single-token decode attention behind one of two impls:
+    """Paged single-token decode attention behind one of two impls.
+
+    Pools are in the serve layout ``([L,] P, page, KVH * D)`` (see
+    ``paged_kernel``; scales ``([L,] P, page, KVH)``).  ``layer`` (a traced
+    int32 scalar) picks one layer of layer-stacked ``(L, P, page, ...)``
+    pools — how the scanned decode step passes its pools, so they are
+    updated in place rather than re-stacked; None means the pools are a
+    single layer's ``(P, page, ...)``.
 
       * ``"fused"``     — the gather-fused Pallas kernel: the page table
         drives the grid, each K/V page streams HBM->VMEM straight into the
@@ -96,12 +119,14 @@ def paged_gqa_decode_attention(q, k_pages, v_pages, page_table, pos, *,
     if impl == "auto":
         impl = "reference" if on_cpu() else "fused"
     if impl == "reference":
+        k_pages, v_pages, k_scales, v_scales = _oracle_pools(
+            layer, q.shape[-1], k_pages, v_pages, k_scales, v_scales)
         return paged_decode_attention_ref(q, k_pages, v_pages, page_table,
                                           pos, k_scales=k_scales,
                                           v_scales=v_scales, window=window)
     if impl != "fused":
         raise ValueError(f"impl={impl!r} (want 'auto', 'fused' or 'reference')")
     return paged_decode_attention(q, k_pages, v_pages, page_table,
-                                  pos.astype(jnp.int32), k_scales=k_scales,
-                                  v_scales=v_scales, window=window,
-                                  interpret=on_cpu())
+                                  pos.astype(jnp.int32), layer=layer,
+                                  k_scales=k_scales, v_scales=v_scales,
+                                  window=window, interpret=on_cpu())

@@ -9,11 +9,13 @@ import jax.numpy as jnp
 from repro.models.common import NEG_INF, decode_attention_ref  # noqa: F401
 
 
-def gather_pages(pages: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
+def gather_pages(pages: jnp.ndarray, page_table: jnp.ndarray,
+                 layer=None) -> jnp.ndarray:
     """(P, page, ...) pool + (B, n_blocks) table -> (B, n_blocks*page, ...)
     position-ordered dense view (block i of row b = physical page
-    ``page_table[b, i]``)."""
-    g = pages[page_table]                     # (B, n_blocks, page, ...)
+    ``page_table[b, i]``).  With ``layer``, ``pages`` is a layer-stacked
+    ``(L, P, page, ...)`` pool and the view is of layer ``layer``."""
+    g = pages[page_table] if layer is None else pages[layer, page_table]
     b, nb, ps = g.shape[:3]
     return g.reshape((b, nb * ps) + g.shape[3:])
 

@@ -35,11 +35,11 @@ _E8M0_BIAS = 127.0
 
 
 def _decode_e2m1(codes: jnp.ndarray) -> jnp.ndarray:
-    """Branch-free E2M1 decode: uint8 code (0..15) -> f32 value.
+    """Branch-free E2M1 decode: int32 code (0..15) -> f32 value.
 
     value = sign * (e == 0 ? 0.5*m : (1 + 0.5*m) * 2^(e-1))
     """
-    c = codes.astype(jnp.int32)
+    c = codes
     sign = 1.0 - 2.0 * ((c >> 3) & 1).astype(jnp.float32)
     e = ((c >> 1) & 3).astype(jnp.float32)
     m = (c & 1).astype(jnp.float32)
@@ -54,13 +54,15 @@ def _vmm_kernel(x_ref, codes_ref, scales_ref, out_ref, *, block_k: int,
     k_step = pl.program_id(1)
 
     # ---- Stream Decoder: dequantize the (block_k, bn) weight tile in VMEM
-    packed = codes_ref[...]                          # (bk//2, bn) uint8
+    # bytes widen to int32 first: the TPU has no 8-bit shifts or casts
+    packed = codes_ref[...].astype(jnp.int32)        # (bk//2, bn)
     lo = _decode_e2m1(packed & 0xF)                  # even k
     hi = _decode_e2m1(packed >> 4)                   # odd k
     vals = jnp.stack([lo, hi], axis=1)               # (bk//2, 2, bn)
     vals = vals.reshape(block_k, -1)                 # (bk, bn) interleaved
 
-    exp = scales_ref[...].astype(jnp.float32) - _E8M0_BIAS   # (bk//32, bn)
+    exp = (scales_ref[...].astype(jnp.int32).astype(jnp.float32)
+           - _E8M0_BIAS)                                # (bk//32, bn)
     scale = jnp.repeat(jnp.exp2(exp), MX_BLOCK, axis=0)      # (bk, bn)
     w_tile = (vals * scale).astype(jnp.bfloat16)
 

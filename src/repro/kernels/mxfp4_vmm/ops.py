@@ -2,11 +2,11 @@
 
 ``mxfp4_matmul`` is the user-facing op: takes a ``PackedMXFP4`` weight and
 (B, K) activations, dispatches to the Pallas kernel (interpret-mode on CPU,
-compiled on TPU), and falls back to the jnp oracle for shapes the kernel's
-tiling can't cover (tiny smoke configs).  The fallback is *surfaced*: it
-bumps ``FALLBACK_STATS`` and warns once, so production configs silently
-bypassing the kernel are visible (the llama3-8b serve projections are
-asserted tileable in tests).
+compiled on TPU).  On the CPU, shapes the kernel's tiling can't cover (tiny
+smoke configs) fall back to the jnp oracle; the fallback is *surfaced*: it
+bumps ``FALLBACK_STATS`` and warns once (the llama3-8b serve projections
+are asserted tileable in tests).  On an accelerator such a shape raises, so
+no deployment serves through the oracle unnoticed.
 """
 from __future__ import annotations
 
@@ -52,8 +52,9 @@ def mxfp4_matmul(x: jnp.ndarray, w: PackedMXFP4, *,
     ``impl``: "fused" runs the Pallas kernel (interpret-mode on CPU),
     "reference" the jnp oracle, "auto" picks the oracle on CPU (interpret
     mode inside a serve step is orders of magnitude slower) and the kernel
-    on accelerators.  Non-tileable shapes always take the oracle — counted
-    in ``FALLBACK_STATS`` and warned once.
+    on accelerators.  Non-tileable shapes take the oracle on the CPU —
+    counted in ``FALLBACK_STATS`` and warned once — and raise
+    ``ValueError`` on an accelerator.
     """
     if impl not in ("auto", "fused", "reference"):
         raise ValueError(f"impl must be auto|fused|reference, got {impl!r}")
@@ -64,6 +65,11 @@ def mxfp4_matmul(x: jnp.ndarray, w: PackedMXFP4, *,
         impl = "reference" if on_cpu() else "fused"
     tileable = mxfp4_tileable(k, n, block_n=block_n, block_k=block_k)
     if impl == "fused" and not tileable:
+        if not on_cpu():
+            raise ValueError(
+                f"mxfp4_matmul: weight shape ({k}, {n}) is not tileable by "
+                f"the Pallas VMM kernel (block_k={block_k}, "
+                f"block_n={block_n})")
         _note_fallback(k, n)
         impl = "reference"
     if impl == "reference":

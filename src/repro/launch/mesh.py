@@ -12,17 +12,31 @@ Mesh layout:
 The ``model`` axis carries TP/EP/CP (weights, experts, KV$-context); the
 ``data`` axis carries DP and the FSDP weight shard; ``pod`` is the slow
 (DCN-ish) axis used for DP + gradient-compressed cross-pod reduction.
+
+Every mesh in the repo is built by ``make_mesh``, which makes every axis
+``Auto``: sharding hints (``with_sharding_constraint``), ``jnp.repeat``
+and partial-manual ``shard_map`` regions all need Auto axes, and
+``jax.make_mesh`` defaults to Explicit ones.
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes) -> jax.sharding.Mesh:
+    """A mesh of ``shape`` named ``axes`` with every axis ``Auto``, over
+    the first ``prod(shape)`` visible devices."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_small_mesh(n_devices: int | None = None, model_axis: int | None = None):
@@ -31,7 +45,7 @@ def make_small_mesh(n_devices: int | None = None, model_axis: int | None = None)
     n = n_devices or len(devs)
     model = model_axis or 1
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def mesh_chip_count(mesh) -> int:
@@ -54,4 +68,4 @@ def parse_mesh(spec: str):
         raise ValueError(f"mesh {d}x{m} needs {d * m} devices, "
                          f"have {n} (set "
                          f"XLA_FLAGS=--xla_force_host_platform_device_count)")
-    return jax.make_mesh((d, m), ("data", "model"))
+    return make_mesh((d, m), ("data", "model"))

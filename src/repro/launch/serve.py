@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_small_mesh, parse_mesh
 from repro.models.model import build_model
 from repro.parallel.hints import sharding_rules
@@ -176,6 +177,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0,
                     help="model-init seed AND per-request sampling seed")
     args = ap.parse_args(argv)
+    use_compile_cache()
     backend = args.backend or ("continuous" if args.continuous else
                                "speculative" if args.speculative else
                                "static")
@@ -212,7 +214,9 @@ def main(argv=None) -> int:
     if disagg is not None and serve_mesh is not None:
         from repro.parallel.plan import split_mesh
         pmesh, dmesh = split_mesh(serve_mesh, disagg[0], disagg[1])
-    mesh = make_small_mesh()
+    # the ambient mesh of the unsharded paths is ONE device: serving
+    # without --mesh must not spread itself over every visible chip
+    mesh = make_small_mesh(1)
     plan = make_plan(cfg, mesh, global_batch=args.batch, shape_kind="decode")
     max_len = args.prompt_len + args.max_new + 1
     if args.spec_draft is not None and backend == "continuous":

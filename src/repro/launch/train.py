@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, reduced_config
 from repro.data.pipeline import SyntheticTokenPipeline
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_small_mesh
 from repro.models.model import build_model
 from repro.parallel.hints import sharding_rules
@@ -43,6 +44,7 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")

@@ -186,32 +186,44 @@ def layout_for_kind(kind: str) -> CacheLayout:
 # ---------------------------------------------------------------------------
 
 
+# ``layer`` (every paged path below takes it): None when ``pool`` is one
+# layer's ``(P, page, ...)`` leaves; the layer index when the leaves are
+# layer-stacked ``(L, P, page, ...)`` — how scanned segments carry their
+# pools, so each step writes and reads them in place instead of
+# re-stacking a copy of every layer's pool.
+
+
+def _token_index(layer, phys, off):
+    return (phys, off) if layer is None else (layer, phys, off)
+
+
 def scatter_token(pool_leaf: jnp.ndarray, vals: jnp.ndarray, page_table,
-                  pos) -> jnp.ndarray:
+                  pos, layer=None) -> jnp.ndarray:
     """Scatter one token per slot: vals (B, ...) at per-slot position pos."""
     b = vals.shape[0]
-    page = pool_leaf.shape[1]
+    page = pool_leaf.shape[1 if layer is None else 2]
     blk, off = pos // page, pos % page
     phys = page_table[jnp.arange(b), blk]
-    return pool_leaf.at[phys, off].set(vals.astype(pool_leaf.dtype))
+    return pool_leaf.at[_token_index(layer, phys, off)].set(
+        vals.astype(pool_leaf.dtype))
 
 
 def scatter_chunk(pool_leaf: jnp.ndarray, vals: jnp.ndarray, page_table,
-                  positions, ok) -> jnp.ndarray:
+                  positions, ok, layer=None) -> jnp.ndarray:
     """Scatter a chunk of tokens per slot through the page table.
 
     vals: (B, C, ...); positions: (B, C) absolute; ok: (B, C) — entries with
     ``ok=False`` (padding rows / the tail of a short last chunk) are
     redirected to the scratch page so live pages are never corrupted."""
     b, c = positions.shape
-    page = pool_leaf.shape[1]
+    page = pool_leaf.shape[1 if layer is None else 2]
     okf = ok.reshape(-1)
     pos_f = jnp.where(okf, positions.reshape(-1), 0)
     bidx = jnp.repeat(jnp.arange(b), c)
     phys = jnp.where(okf, page_table[bidx, pos_f // page], 0)
     off = jnp.where(okf, pos_f % page, 0)
     flat = vals.reshape((b * c,) + vals.shape[2:]).astype(pool_leaf.dtype)
-    return pool_leaf.at[phys, off].set(flat)
+    return pool_leaf.at[_token_index(layer, phys, off)].set(flat)
 
 
 # ---------------------------------------------------------------------------
@@ -221,55 +233,57 @@ def scatter_chunk(pool_leaf: jnp.ndarray, vals: jnp.ndarray, page_table,
 
 def init_attn_page_pool(cfg: ModelConfig, num_pages: int, page_size: int,
                         dtype=jnp.bfloat16) -> dict:
-    """Physical K/V page pool for one layer: ``(P, page, KVH, HD)``.
+    """Physical K/V page pool for one layer: ``(P, page, KVH * HD)``, every
+    KV head of a token side by side on the (lane-dense, so unpadded on a
+    TPU) last axis; see ``kernels.decode_attention.paged_kernel``.
 
     ``dtype``: bf16 on TPU; CPU serving wants f32 (XLA:CPU re-converts
     bf16 pools to f32 around every gather, doubling the step time).  The
     string dtypes ``"fp8"`` / ``"int8"`` build quantized pools: narrow
     code leaves plus per-token f32 ``k_scale``/``v_scale`` metadata leaves
     of shape ``(P, page, KVH)`` (see ``quant.kv``)."""
-    shape = (num_pages, page_size, cfg.n_kv_heads, cfg.hd)
+    shape = (num_pages, page_size, cfg.n_kv_heads * cfg.hd)
     if kvq.is_quantized_cache_dtype(dtype):
         store = kvq.cache_storage_dtype(dtype)
+        scales = (num_pages, page_size, cfg.n_kv_heads)
         return {"k": jnp.zeros(shape, store), "v": jnp.zeros(shape, store),
-                "k_scale": jnp.ones(shape[:3], kvq.SCALE_DTYPE),
-                "v_scale": jnp.ones(shape[:3], kvq.SCALE_DTYPE)}
+                "k_scale": jnp.ones(scales, kvq.SCALE_DTYPE),
+                "v_scale": jnp.ones(scales, kvq.SCALE_DTYPE)}
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def _scatter_kv_token(pool: dict, k, v, page_table, pos) -> dict:
+def _scatter_kv_token(pool: dict, k, v, page_table, pos, layer) -> dict:
     """Scatter one token's k/v per slot, quantizing on write for fp8/int8
     pools (scale = amax of the token's head vector, fixed at write time)."""
     fmt = kvq.pool_cache_format(pool)
-    if fmt is None:
-        return {"k": scatter_token(pool["k"], k, page_table, pos),
-                "v": scatter_token(pool["v"], v, page_table, pos)}
-    kc, ks = kvq.kv_quantize(k, fmt)
-    vc, vs = kvq.kv_quantize(v, fmt)
-    return {"k": scatter_token(pool["k"], kc, page_table, pos),
-            "v": scatter_token(pool["v"], vc, page_table, pos),
-            "k_scale": scatter_token(pool["k_scale"], ks, page_table, pos),
-            "v_scale": scatter_token(pool["v_scale"], vs, page_table, pos)}
+    vals = {"k": k, "v": v}
+    if fmt is not None:
+        vals["k"], vals["k_scale"] = kvq.kv_quantize(k, fmt)
+        vals["v"], vals["v_scale"] = kvq.kv_quantize(v, fmt)
+    for name in ("k", "v"):                  # (B, KVH, HD) -> (B, KVH * HD)
+        vals[name] = vals[name].reshape(vals[name].shape[:-2] + (-1,))
+    return {name: scatter_token(pool[name], val, page_table, pos, layer)
+            for name, val in vals.items()}
 
 
-def _scatter_kv_chunk(pool: dict, k, v, page_table, positions, ok) -> dict:
+def _scatter_kv_chunk(pool: dict, k, v, page_table, positions, ok,
+                      layer) -> dict:
     """Chunk analogue of ``_scatter_kv_token`` (k/v: (B, C, KVH, HD))."""
     fmt = kvq.pool_cache_format(pool)
-    if fmt is None:
-        return {"k": scatter_chunk(pool["k"], k, page_table, positions, ok),
-                "v": scatter_chunk(pool["v"], v, page_table, positions, ok)}
-    kc, ks = kvq.kv_quantize(k, fmt)
-    vc, vs = kvq.kv_quantize(v, fmt)
-    return {"k": scatter_chunk(pool["k"], kc, page_table, positions, ok),
-            "v": scatter_chunk(pool["v"], vc, page_table, positions, ok),
-            "k_scale": scatter_chunk(pool["k_scale"], ks, page_table,
-                                     positions, ok),
-            "v_scale": scatter_chunk(pool["v_scale"], vs, page_table,
-                                     positions, ok)}
+    vals = {"k": k, "v": v}
+    if fmt is not None:
+        vals["k"], vals["k_scale"] = kvq.kv_quantize(k, fmt)
+        vals["v"], vals["v_scale"] = kvq.kv_quantize(v, fmt)
+    for name in ("k", "v"):            # (B, C, KVH, HD) -> (B, C, KVH * HD)
+        vals[name] = vals[name].reshape(vals[name].shape[:-2] + (-1,))
+    return {name: scatter_chunk(pool[name], val, page_table, positions, ok,
+                                layer)
+            for name, val in vals.items()}
 
 
 def attn_decode_paged(p: dict, x: jnp.ndarray, cfg: ModelConfig, pool: dict,
-                      page_table, pos, *, window=None) -> tuple[jnp.ndarray, dict]:
+                      page_table, pos, *, window=None,
+                      layer=None) -> tuple[jnp.ndarray, dict]:
     """One-token step against a paged cache.
 
     x: (B, D) slot tokens; pos: (B,) int32 per-slot positions (ragged —
@@ -285,10 +299,11 @@ def attn_decode_paged(p: dict, x: jnp.ndarray, cfg: ModelConfig, pool: dict,
     h, hd = cfg.n_heads, cfg.hd
     positions = pos[:, None]                              # (B, 1) ragged RoPE
     q, k, v = layers._qkv(p, x[:, None, :], cfg, positions)
-    new_pool = _scatter_kv_token(pool, k[:, 0], v[:, 0], page_table, pos)
+    new_pool = _scatter_kv_token(pool, k[:, 0], v[:, 0], page_table, pos,
+                                 layer)
     from repro.kernels.decode_attention.ops import paged_gqa_decode_attention
     out = paged_gqa_decode_attention(
-        q[:, 0], new_pool["k"], new_pool["v"], page_table, pos,
+        q[:, 0], new_pool["k"], new_pool["v"], page_table, pos, layer=layer,
         k_scales=new_pool.get("k_scale"), v_scales=new_pool.get("v_scale"),
         window=window)
     out = tp_row_dot(out.reshape(b, h * hd), p["wo"])
@@ -297,7 +312,8 @@ def attn_decode_paged(p: dict, x: jnp.ndarray, cfg: ModelConfig, pool: dict,
 
 def attn_prefill_chunk_paged(p: dict, x: jnp.ndarray, cfg: ModelConfig,
                              pool: dict, page_table, start, valid, *,
-                             window=None) -> tuple[jnp.ndarray, dict]:
+                             window=None,
+                             layer=None) -> tuple[jnp.ndarray, dict]:
     """One prefill chunk against the paged cache.
 
     x: (B, C, D) chunk hidden states; start: (B,) absolute position of
@@ -312,10 +328,11 @@ def attn_prefill_chunk_paged(p: dict, x: jnp.ndarray, cfg: ModelConfig,
     positions = start[:, None] + jnp.arange(c)[None, :]
     q, k, v = layers._qkv(p, x, cfg, positions)
     ok = jnp.arange(c)[None, :] < valid[:, None]
-    new_pool = _scatter_kv_chunk(pool, k, v, page_table, positions, ok)
+    new_pool = _scatter_kv_chunk(pool, k, v, page_table, positions, ok,
+                                 layer)
     from repro.kernels.decode_attention.ops import paged_gqa_multi_attention
     out = paged_gqa_multi_attention(
-        q, new_pool["k"], new_pool["v"], page_table, start,
+        q, new_pool["k"], new_pool["v"], page_table, start, layer=layer,
         k_scales=new_pool.get("k_scale"), v_scales=new_pool.get("v_scale"),
         causal=cfg.causal, window=window, impl="blocked")
     out = tp_row_dot(out.reshape(b, c, h * hd), p["wo"])
@@ -324,7 +341,8 @@ def attn_prefill_chunk_paged(p: dict, x: jnp.ndarray, cfg: ModelConfig,
 
 def attn_decode_multi_paged(p: dict, x: jnp.ndarray, cfg: ModelConfig,
                             pool: dict, page_table, start, valid, *,
-                            window=None) -> tuple[jnp.ndarray, dict]:
+                            window=None,
+                            layer=None) -> tuple[jnp.ndarray, dict]:
     """C-token decode step (speculative verify): the tokens are already
     chosen, so this is chunk-shaped scatter-then-attend, but through the
     ``impl="auto"`` multi-query dispatch — bit-matched per position with
@@ -335,10 +353,11 @@ def attn_decode_multi_paged(p: dict, x: jnp.ndarray, cfg: ModelConfig,
     positions = start[:, None] + jnp.arange(c)[None, :]
     q, k, v = layers._qkv(p, x, cfg, positions)
     ok = jnp.arange(c)[None, :] < valid[:, None]
-    new_pool = _scatter_kv_chunk(pool, k, v, page_table, positions, ok)
+    new_pool = _scatter_kv_chunk(pool, k, v, page_table, positions, ok,
+                                 layer)
     from repro.kernels.decode_attention.ops import paged_gqa_multi_attention
     out = paged_gqa_multi_attention(
-        q, new_pool["k"], new_pool["v"], page_table, start,
+        q, new_pool["k"], new_pool["v"], page_table, start, layer=layer,
         k_scales=new_pool.get("k_scale"), v_scales=new_pool.get("v_scale"),
         window=window)
     out = tp_row_dot(out.reshape(b, c, h * hd), p["wo"])
@@ -368,7 +387,7 @@ def init_mla_page_pool(cfg: ModelConfig, num_pages: int, page_size: int,
 
 
 def mla_decode_paged(p, x, cfg: ModelConfig, pool: dict, page_table, pos, *,
-                     window=None):
+                     window=None, layer=None):
     """Absorbed-matmul MLA decode against a paged latent cache.
 
     Same math as ``layers.mla_decode`` with the latent/k_rope streams
@@ -381,12 +400,13 @@ def mla_decode_paged(p, x, cfg: ModelConfig, pool: dict, page_table, pos, *,
     positions = pos[:, None]
     q_nope, q_rope, c_kv, k_rope = layers._mla_qc(p, x[:, None, :], cfg,
                                                   positions)
-    page = pool["c_kv"].shape[1]
-    new_c = scatter_token(pool["c_kv"], c_kv[:, 0], page_table, pos)
-    new_kr = scatter_token(pool["k_rope"], k_rope[:, 0], page_table, pos)
+    page = pool["c_kv"].shape[1 if layer is None else 2]
+    new_c = scatter_token(pool["c_kv"], c_kv[:, 0], page_table, pos, layer)
+    new_kr = scatter_token(pool["k_rope"], k_rope[:, 0], page_table, pos,
+                           layer)
 
-    c_d = gather_pages(new_c, page_table)                  # (B, S, r)
-    kr_d = gather_pages(new_kr, page_table)                # (B, S, rhd)
+    c_d = gather_pages(new_c, page_table, layer)           # (B, S, r)
+    kr_d = gather_pages(new_kr, page_table, layer)         # (B, S, rhd)
     w_uk = p["w_uk"].reshape(r, h, hd)
     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0].astype(jnp.float32),
                        w_uk.astype(jnp.float32))
@@ -406,7 +426,7 @@ def mla_decode_paged(p, x, cfg: ModelConfig, pool: dict, page_table, pos, *,
 
 
 def mla_decode_multi_paged(p, x, cfg: ModelConfig, pool: dict, page_table,
-                           start, valid, *, window=None):
+                           start, valid, *, window=None, layer=None):
     """C-token absorbed-matmul MLA decode (speculative verify).
 
     Deliberately mirrors ``mla_decode_paged``'s ABSORBED path — not the
@@ -421,11 +441,13 @@ def mla_decode_multi_paged(p, x, cfg: ModelConfig, pool: dict, page_table,
     positions = start[:, None] + jnp.arange(c)[None, :]
     q_nope, q_rope, c_kv, k_rope = layers._mla_qc(p, x, cfg, positions)
     ok = jnp.arange(c)[None, :] < valid[:, None]
-    new_c = scatter_chunk(pool["c_kv"], c_kv, page_table, positions, ok)
-    new_kr = scatter_chunk(pool["k_rope"], k_rope, page_table, positions, ok)
+    new_c = scatter_chunk(pool["c_kv"], c_kv, page_table, positions, ok,
+                          layer)
+    new_kr = scatter_chunk(pool["k_rope"], k_rope, page_table, positions, ok,
+                           layer)
 
-    c_d = gather_pages(new_c, page_table)                  # (B, S, r)
-    kr_d = gather_pages(new_kr, page_table)                # (B, S, rhd)
+    c_d = gather_pages(new_c, page_table, layer)           # (B, S, r)
+    kr_d = gather_pages(new_kr, page_table, layer)         # (B, S, rhd)
     s_len = c_d.shape[1]
     w_uk = p["w_uk"].reshape(r, h, hd)
     q_lat = jnp.einsum("bchd,rhd->bchr", q_nope.astype(jnp.float32),
@@ -447,7 +469,7 @@ def mla_decode_multi_paged(p, x, cfg: ModelConfig, pool: dict, page_table,
 
 
 def mla_prefill_chunk_paged(p, x, cfg: ModelConfig, pool: dict, page_table,
-                            start, valid, *, window=None):
+                            start, valid, *, window=None, layer=None):
     """One MLA prefill chunk: scatter latents, attend via per-head expansion
     of the gathered latent view (the prefill-style path of ``mla_forward``,
     continued at per-slot offsets)."""
@@ -457,10 +479,12 @@ def mla_prefill_chunk_paged(p, x, cfg: ModelConfig, pool: dict, page_table,
     positions = start[:, None] + jnp.arange(c)[None, :]
     q_nope, q_rope, c_kv, k_rope = layers._mla_qc(p, x, cfg, positions)
     ok = jnp.arange(c)[None, :] < valid[:, None]
-    new_c = scatter_chunk(pool["c_kv"], c_kv, page_table, positions, ok)
-    new_kr = scatter_chunk(pool["k_rope"], k_rope, page_table, positions, ok)
-    c_d = gather_pages(new_c, page_table)                  # (B, S, r)
-    kr_d = gather_pages(new_kr, page_table)
+    new_c = scatter_chunk(pool["c_kv"], c_kv, page_table, positions, ok,
+                          layer)
+    new_kr = scatter_chunk(pool["k_rope"], k_rope, page_table, positions, ok,
+                           layer)
+    c_d = gather_pages(new_c, page_table, layer)           # (B, S, r)
+    kr_d = gather_pages(new_kr, page_table, layer)
     s_len = c_d.shape[1]
     k_nope = (c_d @ p["w_uk"]).reshape(b, s_len, h, hd)
     v_d = (c_d @ p["w_uv"]).reshape(b, s_len, h, vhd)
@@ -496,7 +520,8 @@ GQA = register_backend(AttentionBackend(
     decode_paged=attn_decode_paged,
     prefill_chunk_paged=attn_prefill_chunk_paged,
     decode_multi_paged=attn_decode_multi_paged,
-    # (P, page, KVH, HD) codes + (P, page, KVH) scale metadata: KV heads
+    # (P, page, KVH * HD) codes + (P, page, KVH) scale metadata: KV heads
+    # (the lane axis splits into whole heads: KVH divides by the TP degree)
     paged_partition_spec={"k": 2, "v": 2, "k_scale": 2, "v_scale": 2},
 ))
 

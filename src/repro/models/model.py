@@ -236,7 +236,7 @@ def _commit_state_rows(state, new, ok):
 
 def _block_decode_paged(kind: str, p: dict, x, cfg: ModelConfig, window,
                         pool, page_table, pos, moe_impl: str,
-                        state=None, state_ok=None):
+                        state=None, state_ok=None, layer=None):
     """Paged analogue of ``_block_decode``: per-slot ragged positions and
     K/V streamed through the page table.  x: (B, D).
 
@@ -244,7 +244,8 @@ def _block_decode_paged(kind: str, p: dict, x, cfg: ModelConfig, window,
     single-token recurrence over their slot-indexed ``state`` rows and
     commit only rows flagged by ``state_ok`` (slots actually decoding).
     Returns ``(x, new_pool, new_state)`` — stateless kinds pass their
-    (possibly empty) state through untouched.
+    (possibly empty) state through untouched.  ``layer``: this block's
+    index into a layer-stacked ``pool`` (see ``attention_backends``).
 
     The ``tp_psum`` marks close the Megatron column->row pairs when this
     traces inside the sharded serve path's manual region (one reduction
@@ -259,7 +260,7 @@ def _block_decode_paged(kind: str, p: dict, x, cfg: ModelConfig, window,
     if kind == "hybrid":
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
         a, c = be.decode_paged(p["attn"], h, cfg, pool, page_table, pos,
-                               window=window)
+                               window=window, layer=layer)
         s, st = ssm_lib.ssm_decode_step(h, p["ssm"], cfg, state)
         mix = 0.5 * (rmsnorm(a, p["attn_out_norm"], cfg.norm_eps)
                      + rmsnorm(s, p["ssm_out_norm"], cfg.norm_eps))
@@ -270,7 +271,7 @@ def _block_decode_paged(kind: str, p: dict, x, cfg: ModelConfig, window,
         raise NotImplementedError(kind)
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     a, c = be.decode_paged(p["attn"], h, cfg, pool, page_table, pos,
-                           window=window)
+                           window=window, layer=layer)
     x = x + tp_psum(a).astype(x.dtype)
     f = _ffn(kind, p, rmsnorm(x[:, None, :], p["ln2"], cfg.norm_eps), cfg,
              moe_impl)[:, 0]
@@ -280,7 +281,8 @@ def _block_decode_paged(kind: str, p: dict, x, cfg: ModelConfig, window,
 
 def _block_prefill_chunk_paged(kind: str, p: dict, x, cfg: ModelConfig,
                                window, pool, page_table, start, valid,
-                               moe_impl: str, state=None, slot_idx=None):
+                               moe_impl: str, state=None, slot_idx=None,
+                               layer=None):
     """Paged chunked-prefill analogue of ``_block_prefill``.  x: (B, C, D);
     start/valid: (B,) per-slot chunk offset and real-token count.
 
@@ -297,7 +299,8 @@ def _block_prefill_chunk_paged(kind: str, p: dict, x, cfg: ModelConfig,
     if kind == "hybrid":
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
         a, c = be.prefill_chunk_paged(p["attn"], h, cfg, pool, page_table,
-                                      start, valid, window=window)
+                                      start, valid, window=window,
+                                      layer=layer)
         rows = _gather_state_rows(state, slot_idx, start)
         s, st = ssm_lib.ssm_forward(h, p["ssm"], cfg, rows, valid=valid)
         mix = 0.5 * (rmsnorm(a, p["attn_out_norm"], cfg.norm_eps)
@@ -309,7 +312,7 @@ def _block_prefill_chunk_paged(kind: str, p: dict, x, cfg: ModelConfig,
         raise NotImplementedError(kind)
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     a, c = be.prefill_chunk_paged(p["attn"], h, cfg, pool, page_table, start,
-                                  valid, window=window)
+                                  valid, window=window, layer=layer)
     x = x + tp_psum(a).astype(x.dtype)
     f = _ffn(kind, p, rmsnorm(x, p["ln2"], cfg.norm_eps), cfg, moe_impl)
     x = x + (f if kind.endswith("_moe") else tp_psum(f).astype(x.dtype))
@@ -318,7 +321,7 @@ def _block_prefill_chunk_paged(kind: str, p: dict, x, cfg: ModelConfig,
 
 def _block_decode_multi_paged(kind: str, p: dict, x, cfg: ModelConfig,
                               window, pool, page_table, start, valid,
-                              moe_impl: str):
+                              moe_impl: str, layer=None):
     """Multi-token paged decode (speculative verify): x: (B, C, D) chosen
     tokens at per-slot offsets ``start`` with ``valid`` real rows.  Same
     block shape as ``_block_prefill_chunk_paged`` but dispatched through
@@ -333,11 +336,45 @@ def _block_decode_multi_paged(kind: str, p: dict, x, cfg: ModelConfig,
             f"engine gates speculation off for stateful layouts")
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     a, c = be.decode_multi_paged(p["attn"], h, cfg, pool, page_table, start,
-                                 valid, window=window)
+                                 valid, window=window, layer=layer)
     x = x + tp_psum(a).astype(x.dtype)
     f = _ffn(kind, p, rmsnorm(x, p["ln2"], cfg.norm_eps), cfg, moe_impl)
     x = x + (f if kind.endswith("_moe") else tp_psum(f).astype(x.dtype))
     return x, c
+
+
+def _run_segment(seg: Segment, block, x, stack, pools, states):
+    """Run one segment's paged blocks: ``block(kind, p, x, pool, state,
+    layer) -> (x, pool, state)`` for every kind of every repetition.
+
+    A scanned segment (``reps > 1``) carries its layer-stacked pools
+    through the scan and hands each block the whole stack plus its layer
+    index: the blocks write their new K/V into the stack and read it in
+    place.  (As scan inputs and outputs the pools would be re-stacked
+    into a second buffer — twice the pool's bytes live at once, and the
+    whole pool copied every step.)  Per-slot recurrent ``states`` are
+    small and stay scan inputs/outputs.  Returns ``(x, pools, states)``;
+    ``states`` is None in and out for stateless calls."""
+    kinds = seg.kinds
+    no_state = ({},) * len(kinds)
+
+    def seg_step(carry, ps, ss, li):
+        xc, cs = carry
+        new_cs, new_ss = [], []
+        for kind, p, c, s in zip(kinds, ps, cs, no_state if ss is None
+                                 else ss):
+            xc, nc, ns = block(kind, p, xc, c, s, li)
+            new_cs.append(nc)
+            new_ss.append(ns)
+        return (xc, tuple(new_cs)), (None if ss is None else tuple(new_ss))
+
+    if seg.reps == 1:
+        (x, pools), states = seg_step((x, pools), stack, states, None)
+        return x, pools, states
+    (x, pools), states = jax.lax.scan(
+        lambda carry, xs: seg_step(carry, *xs), (x, pools),
+        (stack, states, jnp.arange(seg.reps, dtype=jnp.int32)))
+    return x, pools, states
 
 
 def _block_decode(kind: str, p: dict, x, cfg: ModelConfig, window, cache,
@@ -650,39 +687,21 @@ class Model:
         new_pools = []
         new_states = [] if states is not None else None
         for si, seg in enumerate(self.plan):
-            stack = params["stacks"][si]
             # sliding-window segments index their own (ring) page space
             tbl = (ring_table if (seg.window is not None
                                   and ring_table is not None) else page_table)
 
-            def seg_step(xc, layer, seg=seg, tbl=tbl):
-                if states is None:
-                    ps, cs = layer
-                    ss = ({},) * len(seg.kinds)
-                else:
-                    ps, cs, ss = layer
-                new_cs, new_ss = [], []
-                for kind, p, c, s in zip(seg.kinds, ps, cs, ss):
-                    xc, nc, ns = _block_prefill_chunk_paged(
-                        kind, p, xc, cfg, seg.window, c, tbl, start,
-                        valid, self.moe_impl, state=s, slot_idx=slot_idx)
-                    new_cs.append(nc)
-                    new_ss.append(ns)
-                if states is None:
-                    return xc, tuple(new_cs)
-                return xc, (tuple(new_cs), tuple(new_ss))
+            def block(kind, p, xc, c, s, li, seg=seg, tbl=tbl):
+                return _block_prefill_chunk_paged(
+                    kind, p, xc, cfg, seg.window, c, tbl, start, valid,
+                    self.moe_impl, state=s, slot_idx=slot_idx, layer=li)
 
-            layer = ((stack, pools[si]) if states is None
-                     else (stack, pools[si], states[si]))
-            if seg.reps == 1:
-                x, ys = seg_step(x, layer)
-            else:
-                x, ys = jax.lax.scan(seg_step, x, layer)
-            if states is None:
-                new_pools.append(ys)
-            else:
-                new_pools.append(ys[0])
-                new_states.append(ys[1])
+            x, nc, ns = _run_segment(seg, block, x, params["stacks"][si],
+                                     pools[si],
+                                     None if states is None else states[si])
+            new_pools.append(nc)
+            if states is not None:
+                new_states.append(ns)
         return x, new_pools, new_states
 
     def decode_step_paged(self, params: dict, tokens: jnp.ndarray,
@@ -730,38 +749,20 @@ class Model:
         new_pools = []
         new_states = [] if states is not None else None
         for si, seg in enumerate(self.plan):
-            stack = params["stacks"][si]
             tbl = (ring_table if (seg.window is not None
                                   and ring_table is not None) else page_table)
 
-            def seg_step(xc, layer, seg=seg, tbl=tbl):
-                if states is None:
-                    ps, cs = layer
-                    ss = ({},) * len(seg.kinds)
-                else:
-                    ps, cs, ss = layer
-                new_cs, new_ss = [], []
-                for kind, p, c, s in zip(seg.kinds, ps, cs, ss):
-                    xc, nc, ns = _block_decode_paged(
-                        kind, p, xc, cfg, seg.window, c, tbl, pos,
-                        self.moe_impl, state=s, state_ok=state_ok)
-                    new_cs.append(nc)
-                    new_ss.append(ns)
-                if states is None:
-                    return xc, tuple(new_cs)
-                return xc, (tuple(new_cs), tuple(new_ss))
+            def block(kind, p, xc, c, s, li, seg=seg, tbl=tbl):
+                return _block_decode_paged(
+                    kind, p, xc, cfg, seg.window, c, tbl, pos, self.moe_impl,
+                    state=s, state_ok=state_ok, layer=li)
 
-            layer = ((stack, pools[si]) if states is None
-                     else (stack, pools[si], states[si]))
-            if seg.reps == 1:
-                x, ys = seg_step(x, layer)
-            else:
-                x, ys = jax.lax.scan(seg_step, x, layer)
-            if states is None:
-                new_pools.append(ys)
-            else:
-                new_pools.append(ys[0])
-                new_states.append(ys[1])
+            x, nc, ns = _run_segment(seg, block, x, params["stacks"][si],
+                                     pools[si],
+                                     None if states is None else states[si])
+            new_pools.append(nc)
+            if states is not None:
+                new_states.append(ns)
         logits = self._head(params, x[:, None, :])[:, 0]
         if states is None:
             return logits, new_pools
@@ -810,22 +811,15 @@ class Model:
         x = shard_hint(x, "act_bsd")
         new_pools = []
         for si, seg in enumerate(self.plan):
-            stack = params["stacks"][si]
 
-            def seg_step(xc, layer, seg=seg):
-                ps, cs = layer
-                new_cs = []
-                for kind, p, cch in zip(seg.kinds, ps, cs):
-                    xc, nc = _block_decode_multi_paged(
-                        kind, p, xc, self.cfg, seg.window, cch, page_table,
-                        pos, valid, self.moe_impl)
-                    new_cs.append(nc)
-                return xc, tuple(new_cs)
+            def block(kind, p, xc, c, s, li, seg=seg):
+                xc, nc = _block_decode_multi_paged(
+                    kind, p, xc, self.cfg, seg.window, c, page_table, pos,
+                    valid, self.moe_impl, layer=li)
+                return xc, nc, s
 
-            if seg.reps == 1:
-                x, nc = seg_step(x, (stack, pools[si]))
-            else:
-                x, nc = jax.lax.scan(seg_step, x, (stack, pools[si]))
+            x, nc, _ = _run_segment(seg, block, x, params["stacks"][si],
+                                    pools[si], None)
             new_pools.append(nc)
         logits = self._head(params, x)                     # (B, C, V)
         return logits, new_pools

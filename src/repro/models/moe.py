@@ -21,9 +21,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.models import common
-from repro.parallel.compat import shard_map
 from repro.models.common import ModelConfig, dense_init, split_keys
 
 

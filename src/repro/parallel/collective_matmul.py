@@ -30,9 +30,8 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.parallel.compat import shard_map
 
 
 def _axis_size(axis_name) -> int:

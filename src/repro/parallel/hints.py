@@ -86,17 +86,12 @@ def _rebuild_for_context(sharding):
     rejected (including by the backward pass).  Keep only spec axes that
     are Auto in the ambient mesh and bind the spec to that mesh.
     """
-    from jax.sharding import NamedSharding, PartitionSpec
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return sharding
-    if am is None or not getattr(am, "axis_names", ()):
-        return sharding
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec
+    am = jax.sharding.get_abstract_mesh()
     if tuple(am.axis_names) != tuple(sharding.mesh.axis_names):
         return sharding
-    types = dict(zip(am.axis_names, am.axis_types))
-    manual = {a for a, t in types.items() if "Manual" in str(t)}
+    manual = {a for a, t in zip(am.axis_names, am.axis_types)
+              if t == AxisType.Manual}
     if not manual:
         return sharding
     new = []

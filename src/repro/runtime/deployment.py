@@ -54,6 +54,7 @@ import jax.numpy as jnp
 
 from repro.core import hardware
 from repro.core.hbmco import CANDIDATE_CO, HBMCOConfig, hbmco_by_name
+from repro.launch.mesh import make_mesh
 from repro.models.footprint import compute_footprint
 from repro.quant import formats
 from repro.quant import kv as kvq
@@ -215,9 +216,9 @@ class DeploymentSpec:
             except ValueError:
                 raise ValueError(f"mesh spec wants 'DxM', got {mesh!r}") \
                     from None
-            return jax.make_mesh((d, m), ("data", "model"))
-        d, m = mesh
-        return jax.make_mesh((int(d), int(m)), ("data", "model"))
+        else:
+            d, m = mesh
+        return make_mesh((int(d), int(m)), ("data", "model"))
 
     # ---------------- resolution ----------------
     def resolve(self, model, params=None, mesh=None, *, draft=None,

@@ -44,11 +44,11 @@ from typing import Any, Callable, Iterable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.model import Model
 from repro.parallel import hints
-from repro.parallel.compat import shard_map
 from repro.quant import kv as kvq
 from repro.quant.linear import quantize_params
 from repro.runtime import sampling
@@ -626,6 +626,17 @@ class ContinuousServeEngine:
                 functools.partial(self._copy_page_impl,
                                   self._draft_pool_model.plan),
                 donate_argnums=(0,))
+        # the pools are built where they live: per-shard pools (each device
+        # holds its model-axis slice of every physical page, one shared
+        # logical page-id space) never pass through one device whole
+        self._init_pools = jax.jit(
+            functools.partial(
+                self._pool_model.init_paged_cache, num_pages, page_size,
+                dtype=self.cache_dtype,
+                ring_pages=self.ring_pages if lay.has_ring else None),
+            out_shardings=None if self.serve_plan is None
+            else self.serve_plan.pool_shardings(self._pool_model,
+                                                cache_dtype=self.cache_dtype))
         self._step_fn = jax.jit(self._step_impl, donate_argnums=(1, 2, 3))
         self._chunk = jax.jit(self._chunk_impl, donate_argnums=(1, 2))
         self._chunk_scored = jax.jit(self._chunk_scored_impl,
@@ -656,7 +667,10 @@ class ContinuousServeEngine:
         serve plan's TP axis: params/pools enter pre-sliced per their
         specs, the body runs the LOCAL-geometry model (its ``tp_psum``
         marks close each column/row pair), and logits come back
-        replicated.  Page tables, positions, and every sampling tensor
+        replicated.  The region is manual over EVERY mesh axis — the specs
+        name only the TP axis, so the others just replicate — because XLA
+        cannot partition a Pallas (Mosaic) call over an axis left
+        automatic.  Page tables, positions, and every sampling tensor
         stay replicated data, so the jit signature is identical to the
         single-device path — no extra compiles per mesh shape.  The
         speculative draft model passes its own plan/specs; the target's
@@ -675,8 +689,7 @@ class ContinuousServeEngine:
             body, mesh=sp.mesh,
             in_specs=(param_specs, rep, pool_specs, rep)
             + (rep,) * n_extra,
-            out_specs=(rep,) * n_out + (pool_specs,),
-            axis_names={sp.axis}, check_vma=False)
+            out_specs=(rep,) * n_out + (pool_specs,), check_vma=False)
 
     # -- jitted pieces ------------------------------------------------------
     def _step_impl(self, params, pools, states, presence, tokens, pos,
@@ -999,18 +1012,12 @@ class ContinuousServeEngine:
         self._presence_np = np.zeros((self.num_slots, self._vocab), np.bool_)
         self._presence = self._presence_to_device(self._presence_np)
         self._presence_dirty = False
-        self._pools = self._pool_model.init_paged_cache(
-            self.num_pages, self.page_size, dtype=self.cache_dtype,
-            ring_pages=self.ring_pages if lay.has_ring else None)
+        # drop the last session's pools before allocating this one's: both
+        # at once would need twice the pool bytes on the device
+        self._pools = self._states = self._draft_pools = None
+        self._pools = self._init_pools()
         self._states = (self._pool_model.init_state_pools(self.num_slots)
                         if lay.has_state else None)
-        if self.serve_plan is not None:
-            # per-shard pools: each device holds its model-axis slice of
-            # every physical page (shared logical page-id space)
-            self._pools = jax.device_put(
-                self._pools,
-                self.serve_plan.pool_shardings(self._pool_model,
-                                               cache_dtype=self.cache_dtype))
         if self.spec is not None:
             self._draft_pools = self._draft_pool_model.init_paged_cache(
                 self.num_pages, self.page_size, dtype=self.cache_dtype)

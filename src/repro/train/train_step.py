@@ -19,11 +19,11 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.model import Model
 from repro.parallel import compression
-from repro.parallel.compat import shard_map
 from repro.train.optimizer import AdamWConfig, adamw_update, init_opt_state
 
 

@@ -36,6 +36,7 @@ def test_sharded_train_step_matches_single_device(arch):
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced_config
         from repro.models.model import build_model
         from repro.parallel.hints import sharding_rules
@@ -56,7 +57,7 @@ def test_sharded_train_step_matches_single_device(arch):
         l1 = float(m1["loss"])
 
         # 2x4 mesh with the production plan
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         plan = make_plan(cfg, mesh, global_batch=8, shape_kind="train")
         state2 = init_train_state(model, key)
         with mesh, sharding_rules(plan.rules()):
@@ -84,6 +85,7 @@ def test_sharded_decode_matches_single_device():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced_config
         from repro.models.model import build_model
         from repro.parallel.hints import sharding_rules
@@ -99,7 +101,7 @@ def test_sharded_decode_matches_single_device():
         eng = ServeEngine(model, params, max_len=32, donate_cache=False)
         ref = eng.generate({"tokens": toks}, max_new_tokens=8).tokens
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         plan = make_plan(cfg, mesh, global_batch=8, shape_kind="decode")
         with mesh, sharding_rules(plan.rules()):
             eng2 = ServeEngine(model, params, max_len=32,
@@ -123,6 +125,7 @@ def test_sharded_paged_continuous_decode_matches_single_device():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced_config
         from repro.models.model import build_model
         from repro.runtime.engine import ContinuousServeEngine
@@ -150,7 +153,7 @@ def test_sharded_paged_continuous_decode_matches_single_device():
                 tp_reduce=tp_reduce)
 
         ref = engine().run(mk())
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         # roomy pool (prefix hits) + tight pool (forced preemptions)
         seng = engine(mesh)
         got = seng.run(mk())
@@ -165,10 +168,10 @@ def test_sharded_paged_continuous_decode_matches_single_device():
         # one compiled decode step for the whole greedy/sampled mix
         assert seng._step_fn._cache_size() == 1, \\
             seng._step_fn._cache_size()
-        # pools physically shard the KV-head axis 4-way
+        # pools physically shard the KV-head lanes 4-way
         leaf = jax.tree.leaves(seng._pools)[0]
-        assert (leaf.addressable_shards[0].data.shape[-2]
-                == leaf.shape[-2] // 4), leaf.sharding
+        assert (leaf.addressable_shards[0].data.shape[-1]
+                == leaf.shape[-1] // 4), leaf.sharding
         assert (seng.kv_token_bytes_per_device() * 4
                 == engine().kv_token_bytes_per_device())
         # psum production mode: execution coverage (row-sharded weights,
@@ -198,6 +201,7 @@ def test_kv_head_replicated_paged_decode_matches_single_device():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced_config
         from repro.models.model import build_model
         from repro.runtime.engine import ContinuousServeEngine
@@ -222,7 +226,7 @@ def test_kv_head_replicated_paged_decode_matches_single_device():
                 max_len=21, prefill_chunk=5, mesh=mesh)
 
         ref = engine().run(mk())
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         seng = engine(mesh)
         assert seng.serve_plan.kv_repl == 2, seng.serve_plan
         got = seng.run(mk())
@@ -230,9 +234,11 @@ def test_kv_head_replicated_paged_decode_matches_single_device():
             np.testing.assert_array_equal(ref.results[i], got.results[i])
         assert seng._step_fn._cache_size() == 1
         # pools widened to 4 KV heads, sharded 4-way -> 1 head per shard
+        # (the pool's last axis holds KV heads x head_dim side by side)
         leaf = jax.tree.leaves(seng._pools)[0]
-        assert leaf.shape[-2] == 4, leaf.shape
-        assert leaf.addressable_shards[0].data.shape[-2] == 1, leaf.sharding
+        assert leaf.shape[-1] == 4 * cfg.hd, leaf.shape
+        assert leaf.addressable_shards[0].data.shape[-1] == cfg.hd, \
+            leaf.sharding
         # accounting: per-device bytes = full / kvh (one head), NOT full/tp
         full = engine().kv_token_bytes_per_device()
         assert seng.kv_token_bytes_per_device() == full // 2
@@ -254,6 +260,7 @@ def test_sharded_speculative_continuous_matches_single_device():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced_config
         from repro.models.model import build_model
         from repro.runtime.engine import ContinuousServeEngine
@@ -285,7 +292,7 @@ def test_sharded_speculative_continuous_matches_single_device():
 
         ref = engine().run(mk())            # non-spec single-device
         sref = engine(spec=sc).run(mk())    # spec single-device
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         seng = engine(mesh, sc)
         got = seng.run(mk())
         for i in range(3):
@@ -297,8 +304,8 @@ def test_sharded_speculative_continuous_matches_single_device():
         assert seng._spec_verify._cache_size() == 1
         # draft pools physically shard their KV-head axis over the mesh
         leaf = jax.tree.leaves(seng._draft_pools)[0]
-        assert (leaf.addressable_shards[0].data.shape[-2]
-                == leaf.shape[-2] // 4), leaf.sharding
+        assert (leaf.addressable_shards[0].data.shape[-1]
+                == leaf.shape[-1] // 4), leaf.sharding
         print("ok", got.spec_windows, round(got.accepted_per_window, 3))
     """)
     assert "ok" in out
@@ -311,6 +318,7 @@ def test_elastic_checkpoint_restore_across_meshes():
         import os, tempfile
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced_config
         from repro.models.model import build_model
         from repro.parallel.hints import sharding_rules
@@ -327,7 +335,7 @@ def test_elastic_checkpoint_restore_across_meshes():
                                               cfg.vocab_size)}
         ckpt_dir = tempfile.mkdtemp()
 
-        mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_a = make_mesh((2, 4), ("data", "model"))
         plan_a = make_plan(cfg, mesh_a, global_batch=8, shape_kind="train")
         state = init_train_state(model, key)
         with mesh_a, sharding_rules(plan_a.rules()):
@@ -335,7 +343,7 @@ def test_elastic_checkpoint_restore_across_meshes():
         ckpt_lib.save_checkpoint(ckpt_dir, 1, state)
 
         # "restart" on a different topology
-        mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_b = make_mesh((4, 2), ("data", "model"))
         plan_b = make_plan(cfg, mesh_b, global_batch=8, shape_kind="train")
         template = init_train_state(model, key)
         sh = type(template)(params=plan_b.param_shardings(template.params),

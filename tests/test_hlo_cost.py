@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core.hlo_cost import HloModule, analyze_hlo_text
+from repro.launch.mesh import make_mesh
 
 
 def _walk(fn, *args):
@@ -99,14 +100,14 @@ def test_collectives_inside_scan_are_multiplied():
         pytest.skip("needs >1 device")
     from jax.sharding import PartitionSpec as P
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("x",))
+    mesh = make_mesh((n,), ("x",))
 
     def f(xs):
         def step(c, x):
             return c + jax.lax.psum(x, "x"), None
         return jax.lax.scan(step, jnp.zeros_like(xs[0]), xs)[0]
 
-    from repro.parallel.compat import shard_map
+    from jax import shard_map
     g = jax.jit(shard_map(f, mesh=mesh, in_specs=P(None, "x"),
                           out_specs=P("x")))
     xs = jax.ShapeDtypeStruct((10, 8 * n), jnp.float32)

@@ -44,6 +44,7 @@ def test_moe_ep_multidevice():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config, reduced_config
+        from repro.launch.mesh import make_mesh
         from repro.models import moe as moe_lib
         key = jax.random.PRNGKey(0)
         cfg = reduced_config(get_config('deepseek-v2-lite-16b'))
@@ -51,7 +52,7 @@ def test_moe_ep_multidevice():
         x = jax.random.normal(jax.random.fold_in(key, 1),
                               (4, 16, cfg.d_model), jnp.bfloat16)
         dense = moe_lib.moe_dense(x, p, cfg)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         with mesh:
             ep = jax.jit(lambda x, p: moe_lib.moe_ep(
                 x, p, cfg, mesh, 'model',
@@ -65,15 +66,42 @@ def test_moe_ep_multidevice():
     assert "ok" in r.stdout
 
 
+@pytest.fixture
+def restore_cache_dir():
+    """The entry points turn on the persistent compilation cache for their
+    process; keep that from leaking into the rest of this test worker."""
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_dir_rule(monkeypatch, tmp_path, restore_cache_dir):
+    """A set JAX_COMPILATION_CACHE_DIR is left to JAX; otherwise the cache
+    goes to the fixed, git-ignored .jax_cache/ at the checkout root."""
+    import jax
+    from repro.launch import compile_cache
+    jax.config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir is None
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    fixed = os.path.join(REPO, ".jax_cache")
+    assert compile_cache.use_compile_cache() == fixed
+    assert jax.config.jax_compilation_cache_dir == fixed
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
 @pytest.mark.slow
-def test_train_launcher(tmp_path):
+def test_train_launcher(tmp_path, restore_cache_dir):
     from repro.launch.train import main
     rc = main(["--arch", "qwen3-14b", "--steps", "6", "--batch", "2",
                "--seq", "32", "--ckpt-dir", str(tmp_path / "ckpt")])
     assert rc == 0
 
 
-def test_serve_launcher():
+def test_serve_launcher(restore_cache_dir):
     from repro.launch.serve import main
     rc = main(["--arch", "h2o-danube-1.8b", "--batch", "2",
                "--prompt-len", "16", "--max-new", "8"])

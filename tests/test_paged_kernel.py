@@ -37,6 +37,11 @@ def _paged_case(seed, B, H, KVH, D, page, n_blocks, dtype=jnp.float32,
     return q, k_pages, v_pages, table, pos
 
 
+def _lanes(pages):
+    """(P, page, KVH, D) -> the kernel's serve layout (P, page, KVH * D)."""
+    return pages.reshape(pages.shape[:2] + (-1,))
+
+
 @pytest.mark.parametrize("B,H,KVH,D,page,n_blocks,dtype", [
     (3, 8, 2, 32, 8, 5, jnp.float32),     # GQA 4:1
     (2, 16, 2, 64, 16, 3, jnp.float32),   # GQA 8:1
@@ -48,8 +53,8 @@ def test_fused_exact_matches_oracle_bitwise(B, H, KVH, D, page, n_blocks,
     q, kp, vp, table, pos = _paged_case(0, B, H, KVH, D, page, n_blocks,
                                         dtype=dtype)
     ref = paged_decode_attention_ref(q, kp, vp, table, pos)
-    out = paged_decode_attention(q, kp, vp, table, pos, accum="exact",
-                                 interpret=True)
+    out = paged_decode_attention(q, _lanes(kp), _lanes(vp), table, pos,
+                                 accum="exact", interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -57,8 +62,8 @@ def test_fused_exact_matches_oracle_bitwise(B, H, KVH, D, page, n_blocks,
 def test_fused_exact_sliding_window_bitwise(window):
     q, kp, vp, table, pos = _paged_case(window, 2, 8, 2, 32, 8, 5)
     ref = paged_decode_attention_ref(q, kp, vp, table, pos, window=window)
-    out = paged_decode_attention(q, kp, vp, table, pos, window=window,
-                                 accum="exact", interpret=True)
+    out = paged_decode_attention(q, _lanes(kp), _lanes(vp), table, pos,
+                                 window=window, accum="exact", interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -69,9 +74,10 @@ def test_fused_online_close_to_oracle(window):
     q, kp, vp, table, pos = _paged_case(3, 3, 8, 2, 32, 8, 5)
     ref = np.asarray(paged_decode_attention_ref(q, kp, vp, table, pos,
                                                 window=window), np.float32)
-    out = np.asarray(paged_decode_attention(q, kp, vp, table, pos,
-                                            window=window, accum="online",
-                                            interpret=True), np.float32)
+    out = np.asarray(paged_decode_attention(q, _lanes(kp), _lanes(vp), table,
+                                            pos, window=window,
+                                            accum="online", interpret=True),
+                     np.float32)
     np.testing.assert_allclose(out, ref, rtol=2e-6, atol=2e-6)
 
 
@@ -87,8 +93,9 @@ def test_fused_ignores_scratch_page_tail():
     vp = vp.at[0].set(-1e4)
     ref = paged_decode_attention_ref(q, kp, vp, table_scratch, pos)
     for accum in ("exact", "online"):
-        out = paged_decode_attention(q, kp, vp, table_scratch, pos,
-                                     accum=accum, interpret=True)
+        out = paged_decode_attention(q, _lanes(kp), _lanes(vp),
+                                     table_scratch, pos, accum=accum,
+                                     interpret=True)
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(ref, np.float32),
                                    rtol=2e-6, atol=2e-6)
@@ -96,6 +103,7 @@ def test_fused_ignores_scratch_page_tail():
 
 def test_op_wrapper_impl_routing():
     q, kp, vp, table, pos = _paged_case(7, 2, 4, 2, 16, 4, 3)
+    kp, vp = _lanes(kp), _lanes(vp)                  # the serve layout
     ref = paged_gqa_decode_attention(q, kp, vp, table, pos, impl="reference")
     auto = paged_gqa_decode_attention(q, kp, vp, table, pos)   # CPU -> oracle
     np.testing.assert_array_equal(np.asarray(auto), np.asarray(ref))

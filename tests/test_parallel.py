@@ -9,13 +9,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AbstractMesh, Mesh, PartitionSpec as P
 
 from repro.configs import ASSIGNED_ARCHS, get_config, reduced_config
 from repro.launch import shapes as shp
+from repro.launch.mesh import make_mesh
 from repro.models.model import build_model
 from repro.parallel import compression
-from repro.parallel.compat import make_abstract_mesh, shard_map
 from repro.parallel.plan import make_plan
 from repro.train.optimizer import init_opt_state
 
@@ -23,7 +24,7 @@ from repro.train.optimizer import init_opt_state
 def _fake_mesh(shape, axes):
     """AbstractMesh-backed mesh: lets us build NamedShardings for a 512-chip
     topology inside the single-device test process."""
-    return make_abstract_mesh(shape, axes)
+    return AbstractMesh(shape, axes)
 
 
 def _check_divisible(shardings, tree):
@@ -105,7 +106,7 @@ def _ring_devices():
 def test_ring_allgather_matmul_matches_dense():
     from repro.parallel.collective_matmul import ring_allgather_matmul
     n = _ring_devices()
-    mesh = jax.make_mesh((n,), ("model",))
+    mesh = make_mesh((n,), ("model",))
     k, m, nn = 8 * n, 16, 32
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (m, k), jnp.float32)
@@ -124,7 +125,7 @@ def test_ring_allgather_matmul_matches_dense():
 def test_ring_matmul_reducescatter_matches_dense():
     from repro.parallel.collective_matmul import ring_matmul_reducescatter
     n = _ring_devices()
-    mesh = jax.make_mesh((n,), ("model",))
+    mesh = make_mesh((n,), ("model",))
     k, m, nn = 8 * n, 16, 8 * n
     key = jax.random.PRNGKey(2)
     x = jax.random.normal(key, (m, k), jnp.float32)
@@ -184,10 +185,11 @@ def test_paged_serve_plan_specs_and_local_config():
     plan = make_paged_serve_plan(cfg, mesh, reduce="gather")
     lc = plan.local_config(cfg)
     assert (lc.n_heads, lc.n_kv_heads, lc.d_ff) == (2, 1, cfg.d_ff // 4)
-    # pool specs shard the KV-head axis of the (reps-stacked) gqa pools
+    # pool specs shard the KV-head lanes of the (reps-stacked) gqa pools
+    # (L, P, page, KVH * HD)
     specs = plan.pool_specs(model)
     leaf = jax.tree.leaves(specs, is_leaf=lambda s: isinstance(s, P))[0]
-    assert leaf == P(None, None, None, "model", None)
+    assert leaf == P(None, None, None, "model")
     # gather mode: column weights shard, row weights stay replicated
     params = model.init(jax.random.PRNGKey(0))
     pspecs = plan.param_specs(params)
@@ -226,8 +228,9 @@ def test_paged_serve_plan_quantized_pool_and_packed_param_specs():
     dense = jax.tree.leaves(plan.pool_specs(model),
                             is_leaf=lambda s: isinstance(s, P))
     assert len(leaves) == 2 * len(dense)      # + k_scale/v_scale per pool
-    assert set(leaves) == {P(None, None, None, "model", None),   # codes
-                           P(None, None, None, "model")}         # scales
+    # codes (L, P, page, KVH * HD) and scales (L, P, page, KVH) both shard
+    # their last, KV-head axis
+    assert set(leaves) == {P(None, None, None, "model")}
     # packed param children inherit the parent leaf's spec
     params = model.init(jax.random.PRNGKey(0))
     qp = quantize_params(params, "mxfp4")

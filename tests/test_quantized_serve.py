@@ -144,14 +144,20 @@ def _quantized_paged_case(seed, cache, B=3, H=8, KVH=2, D=32, page=8,
     return q, kc, ks, vc, vs, table, pos
 
 
+def _lanes(pages):
+    """(P, page, KVH, D) -> the kernel's serve layout (P, page, KVH * D)."""
+    return pages.reshape(pages.shape[:2] + (-1,))
+
+
 @pytest.mark.parametrize("cd", ["fp8", "int8"])
 def test_quantized_paged_kernel_exact_bitwise(cd):
     """Fused in-loop dequant == oracle dequant, bit for bit."""
     q, kc, ks, vc, vs, table, pos = _quantized_paged_case(3, cd)
     ref = paged_decode_attention_ref(q, kc, vc, table, pos,
                                      k_scales=ks, v_scales=vs)
-    out = paged_decode_attention(q, kc, vc, table, pos, k_scales=ks,
-                                 v_scales=vs, accum="exact", interpret=True)
+    out = paged_decode_attention(q, _lanes(kc), _lanes(vc), table, pos,
+                                 k_scales=ks, v_scales=vs, accum="exact",
+                                 interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
@@ -163,8 +169,8 @@ def test_quantized_paged_kernel_online_close(cd, window):
         q, kc, vc, table, pos, k_scales=ks, v_scales=vs, window=window),
         np.float32)
     out = np.asarray(paged_decode_attention(
-        q, kc, vc, table, pos, k_scales=ks, v_scales=vs, window=window,
-        accum="online", interpret=True), np.float32)
+        q, _lanes(kc), _lanes(vc), table, pos, k_scales=ks, v_scales=vs,
+        window=window, accum="online", interpret=True), np.float32)
     np.testing.assert_allclose(out, ref, rtol=2e-6, atol=2e-6)
 
 

@@ -131,10 +131,13 @@ def test_greedy_identity_with_prefix_cache_hits(spec_runs):
     """Admission through shared prefix pages (skipped prefill) lands in
     the same speculative stream."""
     toks, _, ref, _, _, sep_eng, _ = spec_runs
+    # the second request arrives once the first has finished its prefill
+    # and registered its pages, however slow the host is: arriving during
+    # that prefill it would find nothing to share
     out = sep_eng.run([Request(rid=0, prompt=np.asarray(toks[0]),
                                max_new_tokens=8),
                        Request(rid=1, prompt=np.asarray(toks[0]),
-                               max_new_tokens=8, arrival_time=0.01)])
+                               max_new_tokens=8, arrival_time=0.5)])
     assert out.prefix_hit_tokens > 0
     np.testing.assert_array_equal(ref.results[0], out.results[0])
     np.testing.assert_array_equal(ref.results[0], out.results[1])

@@ -1,0 +1,96 @@
+"""Compile rehearsals: the serve path's Pallas kernels at published widths,
+compiled for a described (not attached) TPU v5e.
+
+Interpret mode on the CPU runs a kernel's arithmetic but none of the TPU
+lowering's rules (block tiling, supported casts and shifts, VMEM), so a
+kernel can pass every interpret-mode test and still be refused by the
+chip's compiler.  These tests compile each kernel with the TPU compiler
+that ships with jaxlib and check that the Pallas call survives into the
+program as a ``tpu_custom_call``.  Nothing runs: they say nothing about
+results or times.
+
+The topology is described inside a module fixture, never at import time:
+only one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import repro.models  # noqa: F401  (import order: models before kernels.ref)
+from repro.kernels.decode_attention.paged_kernel import paged_decode_attention
+from repro.kernels.mxfp4_vmm.kernel import mxfp4_vmm
+from repro.quant.formats import MX_BLOCK
+
+SLOTS, MAX_LEN, PAGE, N_LAYERS = 8, 2048, 16, 32
+N_BLOCKS = MAX_LEN // PAGE
+N_PAGES = 1 + SLOTS * N_BLOCKS
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # an executable compiled for a described chip cannot be read back here:
+    # keep the persistent cache out of it
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                     # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def _compiled_text(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim", [
+    (32, 32, 96),        # phi3-mini-3.8b (MHA)
+    (32, 8, 128),        # llama3-8b (GQA 4:1)
+])
+def test_paged_decode_online_bf16_compiles(one_chip, heads, kv_heads,
+                                           head_dim):
+    """Layer-stacked pools, as a scanned decode step passes them."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = s((N_LAYERS, N_PAGES, PAGE, kv_heads * head_dim), jnp.bfloat16)
+    text = _compiled_text(
+        lambda q, k, v, t, p, layer: paged_decode_attention(
+            q, k, v, t, p, layer=layer),
+        s((SLOTS, heads, head_dim), jnp.bfloat16), pool, pool,
+        s((SLOTS, N_BLOCKS), jnp.int32), s((SLOTS,), jnp.int32),
+        s((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_fp8_scales_compiles(one_chip):
+    """fp8 codes + per-token f32 scales, dequantized inside the kernel."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = s((N_PAGES, PAGE, 32 * 96), jnp.float8_e4m3fn)
+    scales = s((N_PAGES, PAGE, 32), jnp.float32)
+    text = _compiled_text(
+        lambda q, k, v, t, p, ks, vs: paged_decode_attention(
+            q, k, v, t, p, k_scales=ks, v_scales=vs),
+        s((SLOTS, 32, 96), jnp.bfloat16), pool, pool,
+        s((SLOTS, N_BLOCKS), jnp.int32), s((SLOTS,), jnp.int32),
+        scales, scales)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("k,n", [(3072, 8192), (8192, 3072)])
+def test_mxfp4_vmm_compiles(one_chip, k, n):
+    """phi3-mini's MLP up (d_model -> d_ff) and down projections."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = _compiled_text(
+        lambda x, c, sc: mxfp4_vmm(x, c, sc),
+        s((SLOTS, k), jnp.bfloat16), s((k // 2, n), jnp.uint8),
+        s((k // MX_BLOCK, n), jnp.uint8))
+    assert "tpu_custom_call" in text

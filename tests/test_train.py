@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs import get_config, reduced_config
 from repro.data.pipeline import SyntheticTokenPipeline
+from repro.launch.mesh import make_mesh
 from repro.models.model import build_model
 from repro.train import checkpoint as ckpt_lib
 from repro.train.loop import LoopConfig, run_training
@@ -130,7 +131,7 @@ def test_grad_compression_train_step_runs(tiny):
     """shard_map cross-pod compression path traces and runs on a 1-'pod'
     mesh (numerical path identical to DP mean when pods=1)."""
     cfg, model = tiny
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     opt = AdamWConfig(lr=1e-3)
     step_fn = make_train_step(model, opt, compress_pods=True, mesh=mesh)
     state = init_train_state(model, jax.random.PRNGKey(0), n_pods=1)
