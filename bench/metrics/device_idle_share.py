@@ -1,0 +1,8 @@
+"""device_idle_share (%): the share of the traced window in which no
+operation ran on the chip (one minus the busy union over the window)."""
+
+
+def read(rec):
+    if rec.trace is None or rec.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - rec.trace.busy_s / rec.trace.window_s)

@@ -1,0 +1,10 @@
+"""The benchmark's tests run on the CPU from the checkout's root: the
+``bench`` package and the program (``src/``) are put on the path here."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+for p in (os.path.join(ROOT, "src"), ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
