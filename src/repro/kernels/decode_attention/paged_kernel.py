@@ -276,6 +276,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rep, width), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), *inputs)
     return out.reshape(b, rep, kvh, d).transpose(0, 2, 1, 3).reshape(b, h, d)

@@ -309,6 +309,10 @@ def main(argv=None) -> int:
                   f"requests={n_req} rate={args.arrival_rate}/s "
                   f"steps={stats.steps} occupancy={stats.occupancy:.2f} "
                   f"preemptions={stats.preemptions}")
+            print("host ms by phase: " + " ".join(
+                f"{name.removeprefix('engine.')}={ms:.1f}"
+                for name, ms in sorted(stats.host_ms.items(),
+                                       key=lambda kv: -kv[1])))
             if serve_mesh is not None:
                 sp = llm.serve_plan
                 print(f"mesh: data={serve_mesh.shape['data']} x "

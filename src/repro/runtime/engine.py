@@ -58,6 +58,7 @@ from repro.runtime.scheduler import HANDOFF, RUNNING, Request, Scheduler
 from repro.runtime.speculative import SpeculativeConfig, _check_rewindable
 from repro.runtime.state_cache import (RingPageSpace, model_cache_layout,
                                        ring_pages_needed)
+from repro.runtime.tracing import StepLog, phase_ms, span
 
 
 @dataclasses.dataclass
@@ -242,6 +243,9 @@ class ContinuousStats:
     prompt_tokens: int = 0        # prompt tokens across all admissions
     prefix_hit_tokens: int = 0    # prompt tokens served from shared pages
     cow_events: int = 0
+    host_ms: dict = dataclasses.field(default_factory=dict)
+    # host_ms[span name] = milliseconds of host self time in that phase of
+    # step() over the run (``runtime.tracing``)
     # -- speculative decoding (all zero when speculation is off) --
     spec_windows: int = 0         # draft/verify windows across all requests
     spec_drafted: int = 0         # draft proposals made (gamma per window)
@@ -658,6 +662,7 @@ class ContinuousServeEngine:
                 self._scatter_pages_impl, self._draft_pool_model.plan),
                 donate_argnums=(0,))
         self._sched: Scheduler | None = None
+        self._log = StepLog()
 
     # -- sharded execution --------------------------------------------------
     def _shard_paged(self, fn, *, n_extra: int, n_out: int = 1, plan=None,
@@ -1028,8 +1033,8 @@ class ContinuousServeEngine:
                         self._draft_pool_model,
                         cache_dtype=self.cache_dtype))
         self._t0 = time.monotonic()
-        self._steps, self._occ_sum = 0, 0.0
-        self._n_chunks, self._prefill_tokens = 0, 0
+        self._steps = 0
+        self._log.drain()
         self._spec_windows, self._spec_drafted, self._spec_accepted = 0, 0, 0
         self._requests: list[Request] = []
         self.defrag_every = 0      # run-scoped; run() re-applies its arg
@@ -1208,7 +1213,8 @@ class ContinuousServeEngine:
             outs.append(self._make_output(req, new,
                                           finished=reason is not None))
 
-    def _run_prefill_chunks(self, outs: list[RequestOutput]) -> None:
+    def _run_prefill_chunks(self, outs: list[RequestOutput],
+                            rec) -> None:
         """Advance every PREFILL request by one chunk (one jitted call,
         batched across slots at ragged offsets).
 
@@ -1223,216 +1229,263 @@ class ContinuousServeEngine:
         sched = self._sched
         pre = sched.prefilling()
         c = self.prefill_chunk
-        if self.cache.ring is not None:
-            # ring pages back lazily (admission sizes the full space only);
-            # grow each slot's ring to this chunk's frontier BEFORE the
-            # table snapshot.  ``ring_pages_needed`` sizing makes the
-            # all-or-nothing alloc infallible.
-            for r in pre:
-                n = min(c, r.prompt_len - r.pos)
-                if not self.cache.ensure(r.slot, r.pos + n - 1):
-                    raise RuntimeError(
-                        "ring page pool exhausted during prefill — the "
-                        "engine sizes it via ring_pages_needed(), so this "
-                        "is an allocator invariant violation")
-        bucket = self._bucket(len(pre))
-        need = max(-(-(r.pos + min(c, r.prompt_len - r.pos)) // self.page_size)
-                   for r in pre)
-        nb = min(self._bucket(need), self.max_blocks)
-        tokens = np.zeros((bucket, c), np.int32)
-        tables = np.zeros((bucket, nb), np.int32)      # pad rows -> scratch
-        start = np.zeros((bucket,), np.int32)
-        valid = np.zeros((bucket,), np.int32)
-        table = self.cache.table()
-        rtab = self.cache.ring_table()
-        rtables = (np.zeros((bucket, nb), np.int32)
-                   if rtab is not None else None)
-        slots_ix = (np.zeros((bucket,), np.int32)
-                    if self._layout.has_state else None)
-        for i, r in enumerate(pre):
-            n = min(c, r.prompt_len - r.pos)
-            tokens[i, :n] = r.prompt[r.pos:r.pos + n]
-            tables[i] = table[r.slot, :nb]
-            start[i] = r.pos
-            valid[i] = n
-            if rtables is not None:
-                rtables[i] = rtab[r.slot, :nb]
-            if slots_ix is not None:
-                slots_ix[i] = r.slot
-        samp = sampling.stack_params([r.sampling for r in pre], bucket)
-        extras = sampling.stack_extras([r.sampling for r in pre], bucket)
-        pres = np.zeros((bucket, self._vocab), np.bool_)
-        for i, r in enumerate(pre):
-            pres[i] = self._presence_np[r.slot]
-        sargs = (jnp.asarray(pres), jnp.asarray(tokens), jnp.asarray(tables),
-                 None if rtables is None else jnp.asarray(rtables),
-                 None if slots_ix is None else jnp.asarray(slots_ix),
-                 jnp.asarray(start), jnp.asarray(valid))
-        pargs = (*(jnp.asarray(a) for a in samp),
-                 *(jnp.asarray(a) for a in extras))
-        scored = any(r.sampling.prompt_logprobs for r in pre)
-        plp = None
-        if scored:
-            # tgt[i, j] = the prompt token position start+j predicts (0-pad
-            # past the prompt — those scores are dropped below)
-            tgt = np.zeros((bucket, c), np.int32)
+        with span("engine.prefill.prepare"):
+            if self.cache.ring is not None:
+                # ring pages back lazily (admission sizes the full space
+                # only); grow each slot's ring to this chunk's frontier
+                # BEFORE the table snapshot.  ``ring_pages_needed`` sizing
+                # makes the all-or-nothing alloc infallible.
+                for r in pre:
+                    n = min(c, r.prompt_len - r.pos)
+                    if not self.cache.ensure(r.slot, r.pos + n - 1):
+                        raise RuntimeError(
+                            "ring page pool exhausted during prefill — the "
+                            "engine sizes it via ring_pages_needed(), so "
+                            "this is an allocator invariant violation")
+            bucket = self._bucket(len(pre))
+            need = max(-(-(r.pos + min(c, r.prompt_len - r.pos))
+                         // self.page_size) for r in pre)
+            nb = min(self._bucket(need), self.max_blocks)
+            tokens = np.zeros((bucket, c), np.int32)
+            tables = np.zeros((bucket, nb), np.int32)  # pad rows -> scratch
+            start = np.zeros((bucket,), np.int32)
+            valid = np.zeros((bucket,), np.int32)
+            table = self.cache.table()
+            rtab = self.cache.ring_table()
+            rtables = (np.zeros((bucket, nb), np.int32)
+                       if rtab is not None else None)
+            slots_ix = (np.zeros((bucket,), np.int32)
+                        if self._layout.has_state else None)
             for i, r in enumerate(pre):
-                nxt = r.prompt[int(start[i]) + 1:int(start[i]) + int(valid[i]) + 1]
-                tgt[i, :len(nxt)] = nxt
-            first, lp, plp, self._pools, self._states = self._chunk_scored(
-                self.params, self._pools, self._states, *sargs,
-                jnp.asarray(tgt), *pargs)
-            plp = np.asarray(plp)
-        else:
-            first, lp, self._pools, self._states = self._chunk(
-                self.params, self._pools, self._states, *sargs, *pargs)
-        if self.spec is not None:
-            # the draft pools take the same chunk (same tables/offsets);
-            # speculation is rejected for ring/state layouts, so the ring
-            # and slot operands of sargs never reach this path
-            self._draft_pools = self._draft_chunk(
-                self._draft_params, self._draft_pools, sargs[1], sargs[2],
-                sargs[5], sargs[6])
-        first = np.asarray(first)                      # device sync
-        lp = np.asarray(lp)
-        for i, r in enumerate(pre):
-            r.chunks += 1
-            self._n_chunks += 1
-            self._prefill_tokens += int(valid[i])
-            if plp is not None and r.sampling.prompt_logprobs:
-                # position start+j scores prompt[start+j+1]; the final
-                # chunk's last position predicts the FIRST GENERATED token,
-                # which is not a prompt logprob — drop it
-                n = int(valid[i])
-                keep = n - 1 if int(start[i]) + n == r.prompt_len else n
-                r.prompt_logprobs.extend(float(x) for x in plp[i, :keep])
-            r.pos += int(valid[i])
-            # the window slid past whole blocks during this chunk: return
-            # their ring pages now (between dispatches, never mid-graph)
-            self.cache.reclaim(r.slot, r.pos)
-            if r.pos == r.prompt_len:                  # prefill complete
-                r.state = RUNNING
-                r.tokens.append(int(first[i]))
-                self._presence_np[r.slot, int(first[i])] = True
-                self._presence_dirty = True
-                if r.sampling.logprobs:
-                    r.logprobs.append(float(lp[i]))
-                if r.first_token_time is None:
-                    # a restart re-emits the tokens the client already has
-                    # (seeded streams), so a preempted request keeps its
-                    # original TTFT
-                    r.first_token_time = self._now()
-                self.cache.index_prompt(r.slot, r.prompt)
-                self._progress(r, outs)
-                if self.phase == "prefill" and r.state == RUNNING:
-                    # disaggregated: park the finished chain for transfer;
-                    # the slot (and its pages) stays held until the decode
-                    # engine adopts it
-                    r.state = HANDOFF
+                n = min(c, r.prompt_len - r.pos)
+                tokens[i, :n] = r.prompt[r.pos:r.pos + n]
+                tables[i] = table[r.slot, :nb]
+                start[i] = r.pos
+                valid[i] = n
+                if rtables is not None:
+                    rtables[i] = rtab[r.slot, :nb]
+                if slots_ix is not None:
+                    slots_ix[i] = r.slot
+            samp = sampling.stack_params([r.sampling for r in pre], bucket)
+            extras = sampling.stack_extras([r.sampling for r in pre], bucket)
+            pres = np.zeros((bucket, self._vocab), np.bool_)
+            for i, r in enumerate(pre):
+                pres[i] = self._presence_np[r.slot]
+            sargs = (jnp.asarray(pres), jnp.asarray(tokens),
+                     jnp.asarray(tables),
+                     None if rtables is None else jnp.asarray(rtables),
+                     None if slots_ix is None else jnp.asarray(slots_ix),
+                     jnp.asarray(start), jnp.asarray(valid))
+            pargs = (*(jnp.asarray(a) for a in samp),
+                     *(jnp.asarray(a) for a in extras))
+            scored = any(r.sampling.prompt_logprobs for r in pre)
+            if scored:
+                # tgt[i, j] = the prompt token position start+j predicts
+                # (0-pad past the prompt — those scores are dropped below)
+                tgt = np.zeros((bucket, c), np.int32)
+                for i, r in enumerate(pre):
+                    nxt = r.prompt[int(start[i]) + 1:
+                                   int(start[i]) + int(valid[i]) + 1]
+                    tgt[i, :len(nxt)] = nxt
+                tgt = jnp.asarray(tgt)
+        plp = None
+        with span("engine.prefill.dispatch"):
+            if scored:
+                first, lp, plp, self._pools, self._states = \
+                    self._chunk_scored(self.params, self._pools,
+                                       self._states, *sargs, tgt, *pargs)
+            else:
+                first, lp, self._pools, self._states = self._chunk(
+                    self.params, self._pools, self._states, *sargs, *pargs)
+            if self.spec is not None:
+                # the draft pools take the same chunk (same tables and
+                # offsets); speculation is rejected for ring/state
+                # layouts, so the ring and slot operands of sargs never
+                # reach this path
+                self._draft_pools = self._draft_chunk(
+                    self._draft_params, self._draft_pools, sargs[1],
+                    sargs[2], sargs[5], sargs[6])
+        with span("engine.prefill.wait"):
+            first = np.asarray(first)                  # device sync
+            lp = np.asarray(lp)
+            if plp is not None:
+                plp = np.asarray(plp)
+        rec.prefill_rows += len(pre)
+        rec.prefill_tokens += int(valid.sum())
+        with span("engine.prefill.commit"):
+            for i, r in enumerate(pre):
+                r.chunks += 1
+                if plp is not None and r.sampling.prompt_logprobs:
+                    # position start+j scores prompt[start+j+1]; the final
+                    # chunk's last position predicts the FIRST GENERATED
+                    # token, which is not a prompt logprob — drop it
+                    n = int(valid[i])
+                    keep = n - 1 if int(start[i]) + n == r.prompt_len else n
+                    r.prompt_logprobs.extend(float(x) for x in plp[i, :keep])
+                r.pos += int(valid[i])
+                # the window slid past whole blocks during this chunk:
+                # return their ring pages now (between dispatches, never
+                # mid-graph)
+                self.cache.reclaim(r.slot, r.pos)
+                if r.pos == r.prompt_len:              # prefill complete
+                    r.state = RUNNING
+                    r.tokens.append(int(first[i]))
+                    self._presence_np[r.slot, int(first[i])] = True
+                    self._presence_dirty = True
+                    if r.sampling.logprobs:
+                        r.logprobs.append(float(lp[i]))
+                    if r.first_token_time is None:
+                        # a restart re-emits the tokens the client already
+                        # has (seeded streams), so a preempted request
+                        # keeps its original TTFT
+                        r.first_token_time = self._now()
+                    self.cache.index_prompt(r.slot, r.prompt)
+                    self._progress(r, outs)
+                    if self.phase == "prefill" and r.state == RUNNING:
+                        # disaggregated: park the finished chain for
+                        # transfer; the slot (and its pages) stays held
+                        # until the decode engine adopts it
+                        r.state = HANDOFF
 
     def step(self) -> list[RequestOutput]:
         """One scheduler iteration: admit arrived requests, advance every
         prefilling request by one chunk, run one fused decode step over the
         decoding slots.  Returns the ``RequestOutput`` deltas produced this
         iteration (may be empty — e.g. a chunk that completed no prompt).
-        Never sleeps; with no work due yet it returns immediately."""
+        Never sleeps; with no work due yet it returns immediately.
+
+        Each call is one ``engine.step`` span and one ``StepRecord``
+        (``runtime.tracing``; drained by ``step_log()``)."""
         if self._sched is None:
             return []
+        sched = self._sched
+        preempted = sched.preemptions
+        with self._log.step() as rec:
+            outs = self._step(rec)
+            rec.finished = sum(o.finished for o in outs)
+            rec.preempted = sched.preemptions - preempted
+            rec.pages_live = self.cache.allocator.num_live + (
+                0 if self.cache.ring is None
+                else self.cache.ring.allocator.num_live)
+        return outs
+
+    def step_log(self) -> list:
+        """The ``StepRecord`` of every ``step()`` since the last call (at
+        most ``tracing.MAX_RECORDS``, the newest), oldest first."""
+        return self._log.drain()
+
+    def _step(self, rec) -> list[RequestOutput]:
         sched = self._sched
         outs: list[RequestOutput] = []
         if self.phase != "decode":
             # a decode-phase engine admits only through admit_handoff();
             # preemption victims drain back to the prefill engine instead
             # of re-entering here
-            for r in sched.admit(self._now()):
-                self._slots.set(r.slot, r.sampling)
-                self._presence_np[r.slot] = False
-                self._presence_np[r.slot][np.asarray(r.prompt)] = True
-                self._presence_dirty = True
+            with span("engine.admit"):
+                for r in sched.admit(self._now()):
+                    self._slots.set(r.slot, r.sampling)
+                    self._presence_np[r.slot] = False
+                    self._presence_np[r.slot][np.asarray(r.prompt)] = True
+                    self._presence_dirty = True
+                    rec.admitted += 1
         # -- chunked prefill, interleaved with the decode iterations --
         if sched.prefilling():
-            self._run_prefill_chunks(outs)
+            self._run_prefill_chunks(outs, rec)
         if not sched.decoding():
             return outs
-        # -- capacity + copy-on-write barrier for the decode writes; a
-        # speculative window scatters KV at pos..pos+gamma, so the whole
-        # window's pages are backed (and un-shared) before it starts —
-        # windows never preempt or allocate midway --
-        for req in sched.decoding():
-            if sched.running.get(req.slot) is req:  # not yet preempted
-                upto = req.pos + self._gamma if self.spec is not None else None
-                if sched.ensure_capacity(req, upto=upto):
-                    for blk in range(req.pos // self.page_size,
-                                     (req.pos + self._gamma)
-                                     // self.page_size + 1):
-                        moved = self.cache.cow(req.slot, blk)
-                        if moved is not None:
-                            self._pools = self._copy_page(
-                                self._pools, moved[1], moved[0])
-                            if self.spec is not None:
-                                self._draft_pools = self._copy_page_draft(
-                                    self._draft_pools, moved[1], moved[0])
-        decoding = sched.decoding()
-        if not decoding:
-            return outs
-        if self.defrag_every and (self._steps + 1) % self.defrag_every == 0:
-            gather = self.cache.defrag()
-            if gather is not None:
-                self._pools = self._permute_pools(self._pool_model.plan,
-                                                  self._pools, gather)
-                if self.spec is not None:
-                    self._draft_pools = self._permute_pools(
-                        self._draft_pool_model.plan, self._draft_pools,
-                        gather)
+        with span("engine.decode.prepare"):
+            # -- capacity + copy-on-write barrier for the decode writes; a
+            # speculative window scatters KV at pos..pos+gamma, so the
+            # whole window's pages are backed (and un-shared) before it
+            # starts — windows never preempt or allocate midway --
+            for req in sched.decoding():
+                if sched.running.get(req.slot) is req:  # not yet preempted
+                    upto = (req.pos + self._gamma if self.spec is not None
+                            else None)
+                    if sched.ensure_capacity(req, upto=upto):
+                        for blk in range(req.pos // self.page_size,
+                                         (req.pos + self._gamma)
+                                         // self.page_size + 1):
+                            moved = self.cache.cow(req.slot, blk)
+                            if moved is not None:
+                                rec.cow_copies += 1
+                                self._pools = self._copy_page(
+                                    self._pools, moved[1], moved[0])
+                                if self.spec is not None:
+                                    self._draft_pools = \
+                                        self._copy_page_draft(
+                                            self._draft_pools, moved[1],
+                                            moved[0])
+            decoding = sched.decoding()
+            if not decoding:
+                return outs
+            if (self.defrag_every
+                    and (self._steps + 1) % self.defrag_every == 0):
+                gather = self.cache.defrag()
+                if gather is not None:
+                    self._pools = self._permute_pools(
+                        self._pool_model.plan, self._pools, gather)
+                    if self.spec is not None:
+                        self._draft_pools = self._permute_pools(
+                            self._draft_pool_model.plan, self._draft_pools,
+                            gather)
 
-        tokens = np.zeros((self.num_slots,), np.int32)
-        pos = np.zeros((self.num_slots,), np.int32)
-        # slots still prefilling (or free) must not touch live pages:
-        # their rows are routed to the scratch page for this step
-        step_table = np.zeros_like(self.cache.table())
-        rtab = self.cache.ring_table()
-        ring_step = None if rtab is None else np.zeros_like(rtab)
-        state_ok = (np.zeros((self.num_slots,), np.bool_)
-                    if self._layout.has_state else None)
-        for req in decoding:
-            tokens[req.slot] = req.tokens[-1]
-            pos[req.slot] = req.pos
-            step_table[req.slot] = self.cache.table()[req.slot]
-            if ring_step is not None:
-                ring_step[req.slot] = rtab[req.slot]
-            if state_ok is not None:
-                # non-decoding slots run the step too (fixed batch) but
-                # must not commit their garbage recurrent-state update
-                state_ok[req.slot] = True
-        if self._presence_dirty:       # admissions/releases since last step
-            self._presence = self._presence_to_device(self._presence_np)
-            self._presence_dirty = False
-        if self.spec is not None:
-            return self._spec_window(decoding, tokens, pos, step_table, outs)
-        nxt, lp, self._pools, self._states, self._presence = self._step_fn(
-            self.params, self._pools, self._states, self._presence,
-            jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(step_table),
-            None if ring_step is None else jnp.asarray(ring_step),
-            None if state_ok is None else jnp.asarray(state_ok),
-            *self._slots.arrays())
-        nxt = np.asarray(nxt)                          # device sync
-        lp = np.asarray(lp)
-        self._occ_sum += len(decoding) / self.num_slots
+            tokens = np.zeros((self.num_slots,), np.int32)
+            pos = np.zeros((self.num_slots,), np.int32)
+            # slots still prefilling (or free) must not touch live pages:
+            # their rows are routed to the scratch page for this step
+            step_table = np.zeros_like(self.cache.table())
+            rtab = self.cache.ring_table()
+            ring_step = None if rtab is None else np.zeros_like(rtab)
+            state_ok = (np.zeros((self.num_slots,), np.bool_)
+                        if self._layout.has_state else None)
+            for req in decoding:
+                tokens[req.slot] = req.tokens[-1]
+                pos[req.slot] = req.pos
+                step_table[req.slot] = self.cache.table()[req.slot]
+                if ring_step is not None:
+                    ring_step[req.slot] = rtab[req.slot]
+                if state_ok is not None:
+                    # non-decoding slots run the step too (fixed batch)
+                    # but must not commit their garbage recurrent-state
+                    # update
+                    state_ok[req.slot] = True
+            if self._presence_dirty:   # admissions/releases since last step
+                self._presence = self._presence_to_device(self._presence_np)
+                self._presence_dirty = False
+            args = (jnp.asarray(tokens), jnp.asarray(pos),
+                    jnp.asarray(step_table))
+            if self.spec is None:
+                args += (None if ring_step is None
+                         else jnp.asarray(ring_step),
+                         None if state_ok is None else jnp.asarray(state_ok))
+            sargs = self._slots.arrays()
+        rec.decode_slots = len(decoding)
         self._steps += 1
-        for req in decoding:
-            if sched.running.get(req.slot) is not req:
-                continue
-            req.tokens.append(int(nxt[req.slot]))
-            # mirror the in-step presence update (device already has it)
-            self._presence_np[req.slot, int(nxt[req.slot])] = True
-            if req.sampling.logprobs:
-                req.logprobs.append(float(lp[req.slot]))
-            req.pos += 1
-            self.cache.reclaim(req.slot, req.pos)
-            self._progress(req, outs)
+        if self.spec is not None:
+            return self._spec_window(decoding, *args, sargs, outs)
+        with span("engine.decode.dispatch"):
+            nxt, lp, self._pools, self._states, self._presence = \
+                self._step_fn(self.params, self._pools, self._states,
+                              self._presence, *args, *sargs)
+        with span("engine.decode.wait"):
+            nxt = np.asarray(nxt)                      # device sync
+            lp = np.asarray(lp)
+        with span("engine.decode.commit"):
+            for req in decoding:
+                if sched.running.get(req.slot) is not req:
+                    continue
+                req.tokens.append(int(nxt[req.slot]))
+                # mirror the in-step presence update (device already has it)
+                self._presence_np[req.slot, int(nxt[req.slot])] = True
+                if req.sampling.logprobs:
+                    req.logprobs.append(float(lp[req.slot]))
+                req.pos += 1
+                self.cache.reclaim(req.slot, req.pos)
+                self._progress(req, outs)
         return outs
 
-    def _spec_window(self, decoding, tokens, pos, step_table,
+    def _spec_window(self, decoding, tok_j, pos_j, tab_j, sargs,
                      outs: list[RequestOutput]) -> list[RequestOutput]:
         """One draft/verify window over the decoding slots: gamma jitted
         draft steps (one scan) + one jitted multi-token verify, emitting
@@ -1440,44 +1493,43 @@ class ContinuousServeEngine:
         mix, gamma-window restarts after preemption, and admissions in
         between never retrace."""
         sched = self._sched
-        tok_j, pos_j = jnp.asarray(tokens), jnp.asarray(pos)
-        tab_j = jnp.asarray(step_table)
-        sargs = self._slots.arrays()
-        prop, q_dists, self._draft_pools = self._spec_draft(
-            self._draft_params, self._draft_pools, self._presence,
-            tok_j, pos_j, tab_j, *sargs)
-        out, n_emit, lp, self._pools, self._presence = self._spec_verify(
-            self.params, self._pools, self._presence, tok_j, prop, q_dists,
-            pos_j, tab_j, *sargs)
-        out = np.asarray(out)                          # device sync
-        n_emit = np.asarray(n_emit)
-        lp = np.asarray(lp)
-        self._occ_sum += len(decoding) / self.num_slots
-        self._steps += 1
-        for req in decoding:
-            if sched.running.get(req.slot) is not req:
-                continue
-            n = int(n_emit[req.slot])
-            req.spec_windows += 1
-            req.spec_accepted += n - 1
-            self._spec_windows += 1
-            self._spec_drafted += self._gamma
-            self._spec_accepted += n - 1
-            took = 0
-            for j in range(n):
-                t = int(out[req.slot, j])
-                req.tokens.append(t)
-                self._presence_np[req.slot, t] = True
-                if req.sampling.logprobs:
-                    req.logprobs.append(float(lp[req.slot, j]))
-                took += 1
-                # stop/length can land mid-window: the tail tokens are
-                # never emitted, and the finished slot's presence row
-                # resets on release, so the device copy stays consistent
-                if req.check_finish() is not None:
-                    break
-            req.pos += took
-            self._progress(req, outs)
+        with span("engine.decode.dispatch"):
+            prop, q_dists, self._draft_pools = self._spec_draft(
+                self._draft_params, self._draft_pools, self._presence,
+                tok_j, pos_j, tab_j, *sargs)
+            out, n_emit, lp, self._pools, self._presence = self._spec_verify(
+                self.params, self._pools, self._presence, tok_j, prop,
+                q_dists, pos_j, tab_j, *sargs)
+        with span("engine.decode.wait"):
+            out = np.asarray(out)                      # device sync
+            n_emit = np.asarray(n_emit)
+            lp = np.asarray(lp)
+        with span("engine.decode.commit"):
+            for req in decoding:
+                if sched.running.get(req.slot) is not req:
+                    continue
+                n = int(n_emit[req.slot])
+                req.spec_windows += 1
+                req.spec_accepted += n - 1
+                self._spec_windows += 1
+                self._spec_drafted += self._gamma
+                self._spec_accepted += n - 1
+                took = 0
+                for j in range(n):
+                    t = int(out[req.slot, j])
+                    req.tokens.append(t)
+                    self._presence_np[req.slot, t] = True
+                    if req.sampling.logprobs:
+                        req.logprobs.append(float(lp[req.slot, j]))
+                    took += 1
+                    # stop/length can land mid-window: the tail tokens are
+                    # never emitted, and the finished slot's presence row
+                    # resets on release, so the device copy stays
+                    # consistent
+                    if req.check_finish() is not None:
+                        break
+                req.pos += took
+                self._progress(req, outs)
         return outs
 
     def run(self, requests: Iterable[Request], *, key=None,
@@ -1510,6 +1562,7 @@ class ContinuousServeEngine:
             self.add_request(r, sampling_params=default)
 
         sched = self._sched
+        records = []
         while sched.has_work():
             if not sched.running:
                 nxt_t = sched.next_arrival()
@@ -1519,6 +1572,7 @@ class ContinuousServeEngine:
             for o in self.step():
                 if on_output is not None:
                     on_output(o)
+            records += self._log.drain()
 
         results = {r.rid: np.asarray(r.tokens[:r.max_new_tokens], np.int32)
                    for r in requests}
@@ -1534,12 +1588,10 @@ class ContinuousServeEngine:
         outputs = {r.rid: self._make_output(r, [], finished=True)
                    for r in requests}
         return ContinuousStats(
-            results=results, steps=self._steps,
-            occupancy=self._occ_sum / max(self._steps, 1),
+            results=results,
+            **_step_counts(records, self.num_slots),
             wall=self._now(),
             preemptions=sum(r.preemptions for r in requests),
-            chunks=self._n_chunks,
-            prefill_tokens=self._prefill_tokens,
             prompt_tokens=self.cache.lookup_tokens,
             prefix_hit_tokens=self.cache.hit_tokens,
             cow_events=self.cache.cow_events,
@@ -1719,6 +1771,11 @@ class DisaggServeEngine:
         self.prefill.add_request(req, sampling_params)
         self._requests.append(req)
 
+    def step_log(self) -> list:
+        """Both engines' ``StepRecord``s since the last call, by start."""
+        return sorted(self.prefill.step_log() + self.decode.step_log(),
+                      key=lambda r: r.t0_ns)
+
     def step(self) -> list[RequestOutput]:
         """One disaggregated iteration: prefill chunks, then chain
         transfers (in rid order, stopping at decode backpressure), then
@@ -1759,6 +1816,7 @@ class DisaggServeEngine:
         for r in requests:
             self.add_request(r, sampling_params=default)
         pe, de = self.prefill._sched, self.decode._sched
+        records = []
         while pe.has_work() or de.has_work():
             if not pe.running and not de.running:
                 nxt_t = pe.next_arrival()
@@ -1768,6 +1826,7 @@ class DisaggServeEngine:
             for o in self.step():
                 if on_output is not None:
                     on_output(o)
+            records += self.step_log()
 
         results = {r.rid: np.asarray(r.tokens[:r.max_new_tokens], np.int32)
                    for r in requests}
@@ -1784,12 +1843,10 @@ class DisaggServeEngine:
                    for r in requests}
         pf, dc, ho = self.prefill, self.decode, self.handoff
         return ContinuousStats(
-            results=results, steps=dc._steps,
-            occupancy=dc._occ_sum / max(dc._steps, 1),
+            results=results,
+            **_step_counts(records, dc.num_slots),
             wall=pf._now(),
             preemptions=sum(r.preemptions for r in requests),
-            chunks=pf._n_chunks,
-            prefill_tokens=pf._prefill_tokens,
             prompt_tokens=pf.cache.lookup_tokens,
             prefix_hit_tokens=pf.cache.hit_tokens,
             cow_events=pf.cache.cow_events + dc.cache.cow_events,
@@ -1802,6 +1859,20 @@ class DisaggServeEngine:
             handoff_shared_tokens=ho.shared_tokens,
             per_request=per_request,
             outputs=outputs)
+
+
+def _step_counts(records, num_slots: int) -> dict:
+    """``ContinuousStats``' step counters from a run's ``StepRecord``s (of
+    both engines when disaggregated): decode iterations and their mean
+    occupancy of ``num_slots``, chunk rows and prompt tokens computed, and
+    host time by phase."""
+    slots = [r.decode_slots for r in records if r.decode_slots]
+    return dict(
+        steps=len(slots),
+        occupancy=sum(n / num_slots for n in slots) / max(len(slots), 1),
+        chunks=sum(r.prefill_rows for r in records),
+        prefill_tokens=sum(r.prefill_tokens for r in records),
+        host_ms=phase_ms(records))
 
 
 def serve_step_fn(model: Model):

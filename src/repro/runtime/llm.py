@@ -256,6 +256,13 @@ class LLMEngine:
             raise ValueError("add_request()/step() need backend='continuous'")
         return self._eng.step()
 
+    def step_log(self) -> list:
+        """The engine's ``StepRecord``s since the last call (host time by
+        phase and what each ``step()`` did; ``runtime.tracing``)."""
+        if self.backend != "continuous":
+            raise ValueError("step_log() needs backend='continuous'")
+        return self._eng.step_log()
+
     def has_unfinished(self) -> bool:
         return self.backend == "continuous" and self._eng.has_unfinished()
 

@@ -144,6 +144,7 @@ class Scheduler:
         # stream already dominates the weight stream)
         self.max_running = min(self.num_slots,
                                max_running or self.num_slots)
+        self.preemptions = 0
 
     # -- queries ------------------------------------------------------------
     def has_work(self) -> bool:
@@ -300,6 +301,7 @@ class Scheduler:
         self.running.pop(slot)
         self._free_slots.append(slot)
         req.preemptions += 1
+        self.preemptions += 1
         req.state, req.slot, req.pos = PENDING, -1, 0
         # restart re-derives the identical tokens (fold_in(seed, pos)
         # streams); ``emitted`` survives so nothing is streamed twice
