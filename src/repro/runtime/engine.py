@@ -48,6 +48,8 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.model import Model
+from repro.kernels.decode_attention.paged_kernel import (live_walk,
+                                                        pages_per_step)
 from repro.parallel import hints
 from repro.quant import kv as kvq
 from repro.quant.linear import quantize_params
@@ -1021,6 +1023,7 @@ class ContinuousServeEngine:
         # at once would need twice the pool bytes on the device
         self._pools = self._states = self._draft_pools = None
         self._pools = self._init_pools()
+        self._kv_walks = self._decode_walks()
         self._states = (self._pool_model.init_state_pools(self.num_slots)
                         if lay.has_state else None)
         if self.spec is not None:
@@ -1038,6 +1041,22 @@ class ContinuousServeEngine:
         self._spec_windows, self._spec_drafted, self._spec_accepted = 0, 0, 0
         self._requests: list[Request] = []
         self.defrag_every = 0      # run-scoped; run() re-applies its arg
+
+    def _decode_walks(self) -> dict:
+        """Window -> pages the paged decode kernel's walk folds at a time,
+        for each window of the K/V-paged segments (one entry for a model
+        whose layers share a window), from the pools as a shard holds
+        them."""
+        walks = {}
+        for seg, kinds in zip(self._pool_model.plan, self._pools):
+            for pool in kinds:
+                k = pool.get("k")
+                if k is None or seg.window in walks:
+                    continue
+                walks[seg.window] = pages_per_step(
+                    self.page_size, k.sharding.shard_shape(k.shape)[-1],
+                    k.dtype.itemsize, self.max_blocks, seg.window)
+        return walks
 
     def _presence_to_device(self, arr):
         """Host mirror -> device, placement-stable across steps: on a mesh
@@ -1456,6 +1475,14 @@ class ContinuousServeEngine:
             args = (jnp.asarray(tokens), jnp.asarray(pos),
                     jnp.asarray(step_table))
             if self.spec is None:
+                # one layer's pages the decode kernel reads (and walks, in
+                # whole chunks), per window of the model's layers
+                at = pos[[req.slot for req in decoding]]
+                for window, ppb in self._kv_walks.items():
+                    _, live, chunks = live_walk(at, self.page_size, window,
+                                                ppb)
+                    rec.kv_pages_live += int(live.sum())
+                    rec.kv_pages_walked += int(chunks.sum()) * ppb
                 args += (None if ring_step is None
                          else jnp.asarray(ring_step),
                          None if state_ok is None else jnp.asarray(state_ok))

@@ -15,7 +15,8 @@ it).  Each span does two things:
 
 The record also holds what the step did: decode slots run, prefill rows
 and prompt tokens computed, requests admitted, finished and preempted,
-copy-on-write page copies, and pages live after it.  Records go to a
+copy-on-write page copies, pages live after it, and the K/V pages the
+decode kernel read and walked.  Records go to a
 bounded :class:`StepLog` (``MAX_RECORDS``, the oldest dropped first) that
 ``step_log()`` on the engines drains.  All of it is always on, with no
 switch: a step's spans cost some 10 us of host time.
@@ -53,6 +54,11 @@ class StepRecord:
     preempted: int = 0
     cow_copies: int = 0          # pages copied by the copy-on-write barrier
     pages_live: int = 0          # live pool pages after the step
+    # the paged decode kernel's walk, one layer of each window, summed over
+    # the decoding slots: pages holding a position the new token sees, and
+    # those pages rounded up to whole chunks
+    kv_pages_live: int = 0
+    kv_pages_walked: int = 0
 
     @property
     def ns(self) -> int:

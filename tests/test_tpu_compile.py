@@ -56,6 +56,7 @@ def _compiled_text(fn, *shapes):
 @pytest.mark.parametrize("heads,kv_heads,head_dim", [
     (32, 32, 96),        # phi3-mini-3.8b (MHA)
     (32, 8, 128),        # llama3-8b (GQA 4:1)
+    (8, 8, 96),          # phi3-mini-3.8b, a shard of 4 chips
 ])
 def test_paged_decode_online_bf16_compiles(one_chip, heads, kv_heads,
                                            head_dim):
@@ -68,6 +69,50 @@ def test_paged_decode_online_bf16_compiles(one_chip, heads, kv_heads,
         s((SLOTS, heads, head_dim), jnp.bfloat16), pool, pool,
         s((SLOTS, N_BLOCKS), jnp.int32), s((SLOTS,), jnp.int32),
         s((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_windowed_danube_compiles(one_chip):
+    """h2o-danube-1.8b as its cell serves it: GQA 32/8 x 80, a 4096-token
+    window over a 1,024-block ring table, 32 slots, 24 stacked layers."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    slots, n_blocks, ring_pages = 32, 1024, 9249
+    pool = s((24, ring_pages, PAGE, 8 * 80), jnp.bfloat16)
+    text = _compiled_text(
+        lambda q, k, v, t, p, layer: paged_decode_attention(
+            q, k, v, t, p, layer=layer, window=4096),
+        s((slots, 32, 80), jnp.bfloat16), pool, pool,
+        s((slots, n_blocks), jnp.int32), s((slots,), jnp.int32),
+        s((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim,window,cache", [
+    (16, 4, 80, 4096, None),     # h2o-danube-1.8b, a shard of 2 chips
+    (8, 2, 80, 4096, None),      # h2o-danube-1.8b, a shard of 4 chips
+    (25, 5, 64, None, None),     # hymba-1.5b's full-attention layers
+    (25, 5, 64, 1024, "fp8"),    # hymba-1.5b's windowed layers, fp8 KV
+])
+def test_paged_decode_unaligned_widths_compile(one_chip, heads, kv_heads,
+                                               head_dim, window, cache):
+    """K/V widths that are not whole 128-lane tiles take the page walk."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    slots, n_blocks, n_pages = 32, 1024, 9249
+    width = kv_heads * head_dim
+    dt = jnp.bfloat16 if cache is None else jnp.float8_e4m3fn
+    pool = s((N_LAYERS, n_pages, PAGE, width), dt)
+    scales = ([] if cache is None else
+              [s((N_LAYERS, n_pages, PAGE, kv_heads), jnp.float32)] * 2)
+
+    def fn(q, k, v, t, p, layer, *sc):
+        kw = dict(zip(("k_scales", "v_scales"), sc))
+        return paged_decode_attention(q, k, v, t, p, layer=layer,
+                                      window=window, **kw)
+
+    text = _compiled_text(
+        fn, s((slots, heads, head_dim), jnp.bfloat16), pool, pool,
+        s((slots, n_blocks), jnp.int32), s((slots,), jnp.int32),
+        s((), jnp.int32), *scales)
     assert "tpu_custom_call" in text
 
 

@@ -1,6 +1,7 @@
 """Host spans and step records (``runtime.tracing``): how spans nest and
 what a record adds up to, the log's bound, full collections inside a step,
 and the records the continuous engine keeps of every ``step()``."""
+import dataclasses
 import gc
 
 import jax
@@ -196,3 +197,57 @@ def test_llm_and_disagg_step_logs(small):
     assert sum(r.prefill_tokens for r in recs) <= sum(
         r.prompt_len for r in reqs)
     assert sum(r.decode_slots for r in recs) > 0
+
+
+@pytest.mark.parametrize("arch,head_dim", [
+    ("qwen3-14b", None), ("h2o-danube-1.8b", None),
+    # 2 KV heads x 64: a width of one 128-lane tile, so the chunk walk
+    ("qwen3-14b", 64), ("h2o-danube-1.8b", 64),
+], ids=["qwen3-14b", "h2o-danube-1.8b", "qwen3-14b-chunked",
+        "h2o-danube-1.8b-chunked"])
+def test_decode_records_count_the_kernels_live_and_walked_pages(arch,
+                                                                head_dim):
+    """``kv_pages_live``: one layer's pages holding a position each
+    decoding slot's new token sees, counted here block by block from the
+    positions and tables the decode step was handed; ``kv_pages_walked``
+    rounds each slot's up to whole chunks (one page on the page walk)."""
+    cfg = reduced_config(get_config(arch))
+    if head_dim is not None:
+        cfg = dataclasses.replace(cfg, head_dim=head_dim)
+        assert cfg.n_kv_heads * head_dim == 128
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    page, window = 4, cfg.sliding_window
+    eng = ContinuousServeEngine(model, params, num_slots=3, page_size=page,
+                                num_pages=40, max_len=32, prefill_chunk=5)
+    calls, step_fn = [], eng._step_fn
+
+    def spy(*args):       # params, pools, states, presence, tokens, pos, ...
+        pos, table, ring = (np.asarray(a) if a is not None else None
+                            for a in args[5:8])
+        calls.append((pos, table if ring is None else ring))
+        return step_fn(*args)
+
+    eng._step_fn = spy
+    for r in _requests(cfg, n=5, seed=5):
+        r.max_new_tokens += 12                   # decode past the window
+        eng.add_request(r)
+    (ppb,) = eng._kv_walks.values()
+    assert (ppb > 1) == (head_dim is not None)
+    while eng.has_unfinished():
+        eng.step()
+    recs = [r for r in eng.step_log() if r.decode_slots]
+    assert len(recs) == len(calls) > 0
+    for rec, (pos, table) in zip(recs, calls):
+        live = 0
+        for slot, p in enumerate(pos):
+            if table[slot, p // page] == 0:      # not decoding this step
+                continue
+            live += sum(1 for j in range(p // page + 1)
+                        if window is None or j * page + page - 1 > p - window)
+        assert rec.kv_pages_live == live
+        assert live <= rec.kv_pages_walked < live + rec.decode_slots * ppb
+        assert rec.kv_pages_walked % ppb == 0
+    if window is not None:
+        assert max(r.kv_pages_live / r.decode_slots for r in recs) <= (
+            -(-window // page) + 1)
