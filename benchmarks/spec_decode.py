@@ -127,10 +127,8 @@ def build_pair(n_layers: int, draft_layers: int, damp: float, seed: int):
                                n_layers=draft_layers)
     draft = build_model(dcfg)
     dparams = dict(params)
-    # one-layer stacks are UNSTACKED (no lax.scan leading axis)
-    take = (lambda a: a[0]) if draft_layers == 1 \
-        else (lambda a: a[:draft_layers])
-    dparams["stacks"] = jax.tree.map(take, params["stacks"])
+    dparams["stacks"] = jax.tree.map(lambda a: a[:draft_layers],
+                                     params["stacks"])
     return model, params, draft, dparams
 
 

@@ -37,12 +37,21 @@ _ARCH_MODULES = {
 ASSIGNED_ARCHS = list(_ARCH_MODULES)[:10]
 PAPER_ARCHS = list(_ARCH_MODULES)[10:]
 
+# one device's share of an expert-parallel deployment of a listed model:
+# id -> (module, attribute)
+_SHARES = {
+    "deepseek-v2-lite-16b-ep8": ("deepseek_v2_lite_16b", "EP8"),
+}
+
 
 def list_configs() -> list[str]:
     return list(_ARCH_MODULES)
 
 
 def get_config(name: str) -> ModelConfig:
+    if name in _SHARES:
+        mod, attr = _SHARES[name]
+        return getattr(importlib.import_module(f"repro.configs.{mod}"), attr)
     key = name if name in _ARCH_MODULES else None
     if key is None:
         for k, mod in _ARCH_MODULES.items():

@@ -6,9 +6,10 @@ import jax.numpy as jnp
 from repro.kernels import on_cpu
 from repro.kernels.decode_attention.kernel import decode_attention
 from repro.kernels.decode_attention.paged_kernel import paged_decode_attention
+from repro.kernels.decode_attention.paged_mla_kernel import paged_mla_decode
 from repro.kernels.decode_attention.ref import (
     decode_attention_ref, gather_pages, paged_decode_attention_ref,
-    paged_decode_multi_attention_ref,
+    paged_decode_multi_attention_ref, paged_mla_decode_ref,
 )
 
 
@@ -130,3 +131,23 @@ def paged_gqa_decode_attention(q, k_pages, v_pages, page_table, pos, *,
                                   pos.astype(jnp.int32), layer=layer,
                                   k_scales=k_scales, v_scales=v_scales,
                                   window=window, interpret=on_cpu())
+
+
+def paged_mla_decode_attention(q, pages, page_table, pos, *, rank: int,
+                               scale: float, layer=None, impl: str = "auto"):
+    """Paged single-token latent (MLA) decode: absorbed queries ``q`` (B, H,
+    W) over the ``([L,] P, page, W)`` latent pool -> the f32 (B, H, rank)
+    context.  ``"fused"``: the ``paged_mla_decode`` Pallas kernel (live
+    pages only, each read once for every head); ``"reference"``: the
+    gather-then-dense oracle.  ``"auto"`` takes the oracle on CPU and the
+    kernel on accelerators, as ``paged_gqa_decode_attention`` does."""
+    if impl == "auto":
+        impl = "reference" if on_cpu() else "fused"
+    if impl == "reference":
+        return paged_mla_decode_ref(q, pages, page_table, pos, rank=rank,
+                                    scale=scale, layer=layer)
+    if impl != "fused":
+        raise ValueError(f"impl={impl!r} (want 'auto', 'fused' or 'reference')")
+    return paged_mla_decode(q, pages, page_table, pos.astype(jnp.int32),
+                            rank=rank, scale=scale, layer=layer,
+                            interpret=on_cpu())

@@ -217,25 +217,16 @@ def _emit(o_ref, l_ref, acc_ref, seg_t):
     o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-def _chunk_walk_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                       *rest, page: int, ppb: int, scale: float, window,
-                       kvh: int, rep: int, quantized: bool = False):
-    """Grid step ``b``: fold slot ``b``'s live chunks into (m, l, acc) and
-    write its output.  Buffer ``at_ref[0]`` holds (or is loading) the
-    slot's first chunk; the last chunk starts the next slot's first."""
+def _chunk_walk(pt_ref, pos_ref, pools, sem, at_ref, fold, *, page: int,
+                ppb: int, window):
+    """The chunk walk of grid step ``b``: slot ``b``'s live chunks, each
+    live page of a chunk DMAed from its HBM pool into one of two VMEM
+    buffers, and ``fold(cur, first)`` run on buffer ``cur`` once its copies
+    land (its rows hold absolute positions ``first ..``).  ``pools``:
+    ``(hbm pool, buffer, layer)`` triples, copied page by page alike.
+    Buffer ``at_ref[0]`` holds (or is loading) the slot's first chunk; the
+    last chunk starts the next slot's first."""
     b, n_slots = pl.program_id(0), pl.num_programs(0)
-    layer = layer_ref[0]
-    if quantized:
-        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, at_ref,
-         m_ref, l_ref, acc_ref) = rest
-        # the scale rows are this one layer's (see _scale_rows)
-        pools = ((k_hbm, k_buf, layer), (v_hbm, v_buf, layer),
-                 (ks_hbm, ks_buf, 0), (vs_hbm, vs_buf, 0))
-    else:
-        o_ref, k_buf, v_buf, sem, at_ref, m_ref, l_ref, acc_ref = rest
-        pools = ((k_hbm, k_buf, layer), (v_hbm, v_buf, layer))
-    d = q_ref.shape[-1] // kvh
-    rows = ppb * page
 
     def walk(bb):
         return live_walk(pos_ref[bb], page, window, ppb, xp=jnp)
@@ -269,15 +260,11 @@ def _chunk_walk_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
         at_ref[0] = 0
         start(0, *walk(0)[:2], 0, 0)
 
-    pos = pos_ref[b]
     lo, live, n_chunks = walk(b)
     nxt_lo, nxt_live, _ = walk(jnp.minimum(b + 1, n_slots - 1))
     at = at_ref[0]
-    _reset(m_ref, l_ref, acc_ref)
-    seg = _head_segments(kvh, d)                         # (W, KVH)
-    seg_t = _head_segments(kvh, d, transpose=True)       # (KVH, W)
 
-    def fold(c, carry):
+    def body(c, carry):
         cur = (at + c) % 2
 
         @pl.when(c + 1 < n_chunks)
@@ -289,6 +276,36 @@ def _chunk_walk_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
             start(b + 1, nxt_lo, nxt_live, 0, 1 - cur)
 
         wait(b, lo, live, c, cur)
+        fold(cur, (lo + c * ppb) * page)
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, body, 0)
+    at_ref[0] = (at + n_chunks) % 2
+
+
+def _chunk_walk_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                       *rest, page: int, ppb: int, scale: float, window,
+                       kvh: int, rep: int, quantized: bool = False):
+    """Grid step ``b``: fold slot ``b``'s live chunks (``_chunk_walk``)
+    into (m, l, acc) and write its output."""
+    layer = layer_ref[0]
+    if quantized:
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, at_ref,
+         m_ref, l_ref, acc_ref) = rest
+        # the scale rows are this one layer's (see _scale_rows)
+        pools = ((k_hbm, k_buf, layer), (v_hbm, v_buf, layer),
+                 (ks_hbm, ks_buf, 0), (vs_hbm, vs_buf, 0))
+    else:
+        o_ref, k_buf, v_buf, sem, at_ref, m_ref, l_ref, acc_ref = rest
+        pools = ((k_hbm, k_buf, layer), (v_hbm, v_buf, layer))
+    d = q_ref.shape[-1] // kvh
+    rows = ppb * page
+    pos = pos_ref[pl.program_id(0)]
+    _reset(m_ref, l_ref, acc_ref)
+    seg = _head_segments(kvh, d)                         # (W, KVH)
+    seg_t = _head_segments(kvh, d, transpose=True)       # (KVH, W)
+
+    def fold(cur, first):
         k = k_buf[cur].astype(jnp.float32).reshape(rows, -1)   # (rows, W)
         v = v_buf[cur].astype(jnp.float32).reshape(rows, -1)
         if quantized:
@@ -298,12 +315,11 @@ def _chunk_walk_kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
                             precision=_EXACT)
             v = v * jnp.dot(_token_scales(vs_buf[cur], page, kvh), seg_t,
                             precision=_EXACT)
-        _fold(k, v, (lo + c * ppb) * page, pos, q_ref, m_ref, l_ref, acc_ref,
-              seg, seg_t, scale=scale, window=window, rep=rep)
-        return carry
+        _fold(k, v, first, pos, q_ref, m_ref, l_ref, acc_ref, seg, seg_t,
+              scale=scale, window=window, rep=rep)
 
-    jax.lax.fori_loop(0, n_chunks, fold, 0)
-    at_ref[0] = (at + n_chunks) % 2
+    _chunk_walk(pt_ref, pos_ref, pools, sem, at_ref, fold, page=page,
+                ppb=ppb, window=window)
     _emit(o_ref, l_ref, acc_ref, seg_t)
 
 

@@ -99,3 +99,17 @@ def paged_decode_attention_ref(q, k_pages, v_pages, page_table, pos, *,
         v = v.astype(jnp.float32) * gather_pages(v_scales, page_table)[..., None]
     valid = paged_valid_mask(page_table, k_pages.shape[1], pos, window=window)
     return decode_attention_ref(q, k, v, None, valid=valid, scale=scale)
+
+
+def paged_mla_decode_ref(q, pages, page_table, pos, *, rank: int,
+                         scale: float, layer=None):
+    """Gather-then-dense oracle of ``paged_mla_decode``: every head's f32
+    ``(B, H, rank)`` latent context over its slot's rows up to ``pos``.
+    ``q``: (B, H, W) absorbed queries against the ``(.., page, W)`` latent
+    rows (lanes past ``rank`` hold the rotary key, then zeros)."""
+    lat = gather_pages(pages, page_table, layer).astype(jnp.float32)
+    s = jnp.einsum("bhw,bsw->bhs", q.astype(jnp.float32), lat) * scale
+    valid = paged_valid_mask(page_table, pages.shape[-2], pos)
+    s = jnp.where(valid[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhs,bsr->bhr", p, lat[..., :rank])

@@ -19,13 +19,12 @@ SSM state admission — means registering a backend, not editing the engine.
 
 The paged decode kernels behind the GQA backend live in
 ``kernels/decode_attention`` (gather-fused Pallas kernel on accelerators,
-gather-then-dense oracle on CPU); MLA's absorbed-matmul latent decode is
-einsum-based and shares the same page pools and tables.
+gather-then-dense oracle on CPU); MLA's absorbed-matmul latent decode
+streams its latent pages through ``paged_mla_decode`` the same way.
 """
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Callable
 
 import jax
@@ -34,7 +33,8 @@ import jax.numpy as jnp
 from repro.models import layers
 from repro.models.common import NEG_INF, ModelConfig, blocked_attention
 from repro.models.ssm import init_ssm_state
-from repro.kernels.decode_attention.ref import gather_pages, paged_valid_mask
+from repro.kernels.decode_attention.paged_mla_kernel import latent_lanes
+from repro.kernels.decode_attention.ref import gather_pages
 from repro.parallel.hints import tp_row_dot
 from repro.quant import kv as kvq
 
@@ -371,7 +371,9 @@ def attn_decode_multi_paged(p: dict, x: jnp.ndarray, cfg: ModelConfig,
 
 def init_mla_page_pool(cfg: ModelConfig, num_pages: int, page_size: int,
                        dtype=jnp.bfloat16) -> dict:
-    """Latent page pool for one MLA layer (pages hold c_kv + shared k_rope)."""
+    """Latent page pool for one MLA layer: ``(P, page, W)`` rows of c_kv,
+    then the shared k_rope, then zeros to ``W`` (whole 128-lane tiles; see
+    ``kernels.decode_attention.paged_mla_kernel``)."""
     if kvq.is_quantized_cache_dtype(dtype):
         raise NotImplementedError(
             f"cache_dtype={dtype!r} is not implemented for MLA latent page "
@@ -380,49 +382,53 @@ def init_mla_page_pool(cfg: ModelConfig, num_pages: int, page_size: int,
             f"({'/'.join(sorted(kvq.KV_FORMATS))}) is only available for "
             f"GQA-family page pools; for MLA models use a dense cache_dtype "
             f"(None, jnp.bfloat16, jnp.float32) instead.")
-    return {
-        "c_kv": jnp.zeros((num_pages, page_size, cfg.kv_lora_rank), dtype),
-        "k_rope": jnp.zeros((num_pages, page_size, cfg.rope_head_dim), dtype),
-    }
+    width = latent_lanes(cfg.kv_lora_rank, cfg.rope_head_dim)
+    return {"latent": jnp.zeros((num_pages, page_size, width), dtype)}
+
+
+def _latent_rows(c_kv, k_rope, width: int):
+    """c_kv (..., r) and k_rope (..., rhd) side by side, zero-padded to the
+    pool's ``width`` lanes: latent rows (or the absorbed queries that
+    score them)."""
+    pad = jnp.zeros(c_kv.shape[:-1] + (width - c_kv.shape[-1]
+                                       - k_rope.shape[-1],), c_kv.dtype)
+    return jnp.concatenate([c_kv, k_rope.astype(c_kv.dtype), pad], axis=-1)
+
+
+def _absorbed_queries(p, q_nope, q_rope, cfg: ModelConfig, width: int):
+    """f32 (..., H, W) absorbed queries: q_nope through W_uk into the
+    latent space, then q_rope, in the latent rows' lane order."""
+    h, hd, r = cfg.n_heads, cfg.hd, cfg.kv_lora_rank
+    w_uk = p["w_uk"].reshape(r, h, hd).astype(jnp.float32)
+    q_lat = jnp.einsum("...hd,rhd->...hr", q_nope.astype(jnp.float32), w_uk)
+    return _latent_rows(q_lat, q_rope.astype(jnp.float32), width)
 
 
 def mla_decode_paged(p, x, cfg: ModelConfig, pool: dict, page_table, pos, *,
                      window=None, layer=None):
     """Absorbed-matmul MLA decode against a paged latent cache.
 
-    Same math as ``layers.mla_decode`` with the latent/k_rope streams
-    gathered through the page table and a per-slot (ragged) position vector.
+    Same math as ``layers.mla_decode``, with the latent rows read through
+    the page table at per-slot (ragged) positions: on accelerators by the
+    ``paged_mla_decode`` kernel, which walks only each slot's live pages.
     """
     assert window is None, "MLA layers are full-attention"
     b, _ = x.shape
-    h, hd, rhd, vhd, r = (cfg.n_heads, cfg.hd, cfg.rope_head_dim, cfg.v_hd,
-                          cfg.kv_lora_rank)
-    positions = pos[:, None]
+    h, vhd, r = cfg.n_heads, cfg.v_hd, cfg.kv_lora_rank
     q_nope, q_rope, c_kv, k_rope = layers._mla_qc(p, x[:, None, :], cfg,
-                                                  positions)
-    page = pool["c_kv"].shape[1 if layer is None else 2]
-    new_c = scatter_token(pool["c_kv"], c_kv[:, 0], page_table, pos, layer)
-    new_kr = scatter_token(pool["k_rope"], k_rope[:, 0], page_table, pos,
-                           layer)
-
-    c_d = gather_pages(new_c, page_table, layer)           # (B, S, r)
-    kr_d = gather_pages(new_kr, page_table, layer)         # (B, S, rhd)
-    w_uk = p["w_uk"].reshape(r, h, hd)
-    q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0].astype(jnp.float32),
-                       w_uk.astype(jnp.float32))
-    q_eff = jnp.concatenate([q_lat, q_rope[:, 0].astype(jnp.float32)], axis=-1)
-    k_eff = jnp.concatenate([c_d.astype(jnp.float32),
-                             kr_d.astype(jnp.float32)], axis=-1)
-    scale = 1.0 / math.sqrt(hd + rhd)
-    s_ = jnp.einsum("bhr,bsr->bhs", q_eff, k_eff) * scale
-    valid = paged_valid_mask(page_table, page, pos)        # (B, S)
-    s_ = jnp.where(valid[:, None, :], s_, NEG_INF)
-    pattn = jax.nn.softmax(s_, axis=-1)
-    ctx = jnp.einsum("bhs,bsr->bhr", pattn, c_d.astype(jnp.float32))
+                                                  pos[:, None])
+    width = pool["latent"].shape[-1]
+    new = scatter_token(pool["latent"],
+                        _latent_rows(c_kv[:, 0], k_rope[:, 0], width),
+                        page_table, pos, layer)
+    from repro.kernels.decode_attention.ops import paged_mla_decode_attention
+    ctx = paged_mla_decode_attention(
+        _absorbed_queries(p, q_nope[:, 0], q_rope[:, 0], cfg, width), new,
+        page_table, pos, rank=r, scale=cfg.mla_softmax_scale, layer=layer)
     w_uv = p["w_uv"].reshape(r, h, vhd)
     out = jnp.einsum("bhr,rhv->bhv", ctx, w_uv.astype(jnp.float32))
     out = tp_row_dot(out.reshape(b, h * vhd).astype(x.dtype), p["wo"])
-    return out, {"c_kv": new_c, "k_rope": new_kr}
+    return out, {"latent": new}
 
 
 def mla_decode_multi_paged(p, x, cfg: ModelConfig, pool: dict, page_table,
@@ -436,36 +442,25 @@ def mla_decode_multi_paged(p, x, cfg: ModelConfig, pool: dict, page_table,
     bit-for-bit so greedy speculation stays byte-identical."""
     assert window is None, "MLA layers are full-attention"
     b, c, _ = x.shape
-    h, hd, rhd, vhd, r = (cfg.n_heads, cfg.hd, cfg.rope_head_dim, cfg.v_hd,
-                          cfg.kv_lora_rank)
+    h, vhd, r = cfg.n_heads, cfg.v_hd, cfg.kv_lora_rank
     positions = start[:, None] + jnp.arange(c)[None, :]
     q_nope, q_rope, c_kv, k_rope = layers._mla_qc(p, x, cfg, positions)
     ok = jnp.arange(c)[None, :] < valid[:, None]
-    new_c = scatter_chunk(pool["c_kv"], c_kv, page_table, positions, ok,
-                          layer)
-    new_kr = scatter_chunk(pool["k_rope"], k_rope, page_table, positions, ok,
-                           layer)
-
-    c_d = gather_pages(new_c, page_table, layer)           # (B, S, r)
-    kr_d = gather_pages(new_kr, page_table, layer)         # (B, S, rhd)
-    s_len = c_d.shape[1]
-    w_uk = p["w_uk"].reshape(r, h, hd)
-    q_lat = jnp.einsum("bchd,rhd->bchr", q_nope.astype(jnp.float32),
-                       w_uk.astype(jnp.float32))
-    q_eff = jnp.concatenate([q_lat, q_rope.astype(jnp.float32)], axis=-1)
-    k_eff = jnp.concatenate([c_d.astype(jnp.float32),
-                             kr_d.astype(jnp.float32)], axis=-1)
-    scale = 1.0 / math.sqrt(hd + rhd)
-    s_ = jnp.einsum("bchr,bsr->bchs", q_eff, k_eff) * scale
-    idx = jnp.arange(s_len)
+    width = pool["latent"].shape[-1]
+    new = scatter_chunk(pool["latent"], _latent_rows(c_kv, k_rope, width),
+                        page_table, positions, ok, layer)
+    lat = gather_pages(new, page_table, layer).astype(jnp.float32)
+    q_eff = _absorbed_queries(p, q_nope, q_rope, cfg, width)
+    s_ = jnp.einsum("bchw,bsw->bchs", q_eff, lat) * cfg.mla_softmax_scale
+    idx = jnp.arange(lat.shape[1])
     vmask = idx[None, None, :] <= positions[:, :, None]    # (B, C, S)
     s_ = jnp.where(vmask[:, :, None, :], s_, NEG_INF)
     pattn = jax.nn.softmax(s_, axis=-1)
-    ctx = jnp.einsum("bchs,bsr->bchr", pattn, c_d.astype(jnp.float32))
+    ctx = jnp.einsum("bchs,bsr->bchr", pattn, lat[..., :r])
     w_uv = p["w_uv"].reshape(r, h, vhd)
     out = jnp.einsum("bchr,rhv->bchv", ctx, w_uv.astype(jnp.float32))
     out = tp_row_dot(out.reshape(b, c, h * vhd).astype(x.dtype), p["wo"])
-    return out, {"c_kv": new_c, "k_rope": new_kr}
+    return out, {"latent": new}
 
 
 def mla_prefill_chunk_paged(p, x, cfg: ModelConfig, pool: dict, page_table,
@@ -479,23 +474,22 @@ def mla_prefill_chunk_paged(p, x, cfg: ModelConfig, pool: dict, page_table,
     positions = start[:, None] + jnp.arange(c)[None, :]
     q_nope, q_rope, c_kv, k_rope = layers._mla_qc(p, x, cfg, positions)
     ok = jnp.arange(c)[None, :] < valid[:, None]
-    new_c = scatter_chunk(pool["c_kv"], c_kv, page_table, positions, ok,
-                          layer)
-    new_kr = scatter_chunk(pool["k_rope"], k_rope, page_table, positions, ok,
-                           layer)
-    c_d = gather_pages(new_c, page_table, layer)           # (B, S, r)
-    kr_d = gather_pages(new_kr, page_table, layer)
+    new = scatter_chunk(pool["latent"],
+                        _latent_rows(c_kv, k_rope, pool["latent"].shape[-1]),
+                        page_table, positions, ok, layer)
+    lat = gather_pages(new, page_table, layer)             # (B, S, W)
+    r = cfg.kv_lora_rank
+    c_d, kr_d = lat[..., :r], lat[..., r:r + rhd]
     s_len = c_d.shape[1]
     k_nope = (c_d @ p["w_uk"]).reshape(b, s_len, h, hd)
     v_d = (c_d @ p["w_uv"]).reshape(b, s_len, h, vhd)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(kr_d[:, :, None, :],
                                                   (b, s_len, h, rhd))], axis=-1)
-    scale = 1.0 / math.sqrt(hd + rhd)
-    out = blocked_attention(q, k, v_d, causal=cfg.causal, scale=scale,
-                            q_offset=start)
+    out = blocked_attention(q, k, v_d, causal=cfg.causal,
+                            scale=cfg.mla_softmax_scale, q_offset=start)
     out = tp_row_dot(out.reshape(b, c, h * vhd), p["wo"])
-    return out, {"c_kv": new_c, "k_rope": new_kr}
+    return out, {"latent": new}
 
 
 # ---------------------------------------------------------------------------
@@ -527,7 +521,7 @@ GQA = register_backend(AttentionBackend(
 
 MLA = register_backend(AttentionBackend(
     name="mla",
-    paged_leaf_keys=("c_kv", "k_rope"),
+    paged_leaf_keys=("latent",),
     mask_families=("prefix",),
     paged_mask_families=("prefix",),
     init=layers.init_mla,
@@ -545,5 +539,5 @@ MLA = register_backend(AttentionBackend(
     decode_multi_paged=mla_decode_multi_paged,
     # the latent stream is shared by every head: heads shard (w_uk/w_uv
     # columns), the per-token latents replicate across the TP ring
-    paged_partition_spec={"c_kv": None, "k_rope": None},
+    paged_partition_spec={"latent": None},
 ))

@@ -45,6 +45,7 @@ class ModelConfig:
     sliding_window: int | None = None   # SWA window (tokens)
     global_attn_every: int = 0          # hybrid SWA/global interleave (0=never)
     rope_theta: float = 10000.0
+    rope_scaling: "Yarn | None" = None  # YaRN; None = plain RoPE
     causal: bool = True                 # False => encoder-only
 
     # MLA (DeepSeek)
@@ -61,6 +62,11 @@ class ModelConfig:
     moe_d_ff: int = 0
     first_dense_layers: int = 0
     moe_layer_period: int = 1           # 1 = every layer is MoE
+    norm_topk_prob: bool = True         # renormalise the top-k weights
+    # routed experts this device holds, 0 .. n_experts_held - 1 (its share
+    # of an expert-parallel deployment; the router keeps all n_experts
+    # outputs).  0 = all of them.
+    n_experts_held: int = 0
 
     # SSM (Mamba2 SSD)
     ssm: bool = False
@@ -100,6 +106,20 @@ class ModelConfig:
         return self.v_head_dim or self.hd
 
     @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def mla_softmax_scale(self) -> float:
+        """MLA's score scale: 1/sqrt(q head dim), times YaRN's mscale
+        squared where the scaling names ``mscale_all_dim``."""
+        scale = 1.0 / math.sqrt(self.hd + self.rope_head_dim)
+        y = self.rope_scaling
+        if y is not None and y.mscale_all_dim:
+            scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+        return scale
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -126,6 +146,33 @@ class ModelConfig:
     def subquadratic(self) -> bool:
         """Can this arch run the 500k-token long-context decode shape?"""
         return self.family in ("ssm", "hybrid") or self.sliding_window is not None
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling, as DeepSeek-V2's ``rope_scaling`` of type
+    ``yarn`` states it (arXiv:2309.00071)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(y: Yarn, dim: int, theta: float) -> tuple[int, int]:
+    """The rotary pairs between which YaRN blends the interpolated and the
+    original frequencies (DeepSeek-V2's ``yarn_find_correction_range``)."""
+    def at(rotations):
+        return (dim * math.log(y.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(at(y.beta_fast)), 0),
+            min(math.ceil(at(y.beta_slow)), dim - 1))
 
 
 PARAM_DTYPE = jnp.bfloat16
@@ -184,17 +231,33 @@ def swiglu(x: jnp.ndarray, w_gate, w_up, w_down) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_freqs(head_dim: int, theta: float,
+               scaling: Yarn | None = None) -> jnp.ndarray:
+    """Rotary frequencies; under YaRN the pairs past the correction range
+    take the interpolated ones (``1 / factor``), those before it the
+    original, and a linear ramp blends the pairs in between."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if scaling is not None:
+        low, high = yarn_correction_range(scaling, head_dim, theta)
+        ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 0.001), 0.0, 1.0)
+        freqs = freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
+    return freqs
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling: Yarn | None = None) -> jnp.ndarray:
     """x: (B, S, H, D) split-half convention; positions: (B, S) or (S,)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # (D/2,)
+    freqs = rope_freqs(d, theta, scaling)              # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, D/2)
     cos = jnp.cos(ang)[..., None, :]                   # (B, S, 1, D/2)
     sin = jnp.sin(ang)[..., None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -65,8 +65,8 @@ def _qkv(p: dict, x: jnp.ndarray, cfg: ModelConfig, positions):
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     q = shard_hint(q, "act_bshd")
     k = shard_hint(k, "act_bskd")
     return q, k, v
@@ -170,10 +170,11 @@ def _mla_qc(p, x, cfg: ModelConfig, positions):
     h, hd, rhd, r = cfg.n_heads, cfg.hd, cfg.rope_head_dim, cfg.kv_lora_rank
     q = qdot(x, p["wq"]).reshape(b, s, h, hd + rhd)
     q_nope, q_rope = q[..., :hd], q[..., hd:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     ckr = x @ p["w_dkv"]
     c_kv = rmsnorm(ckr[..., :r], p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(ckr[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = apply_rope(ckr[..., None, r:], positions, cfg.rope_theta,
+                        cfg.rope_scaling)[:, :, 0]
     return q_nope, q_rope, c_kv, k_rope
 
 
@@ -190,7 +191,7 @@ def mla_forward(p: dict, x: jnp.ndarray, cfg: ModelConfig, *,
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                                   (b, s, h, rhd))], axis=-1)
-    scale = 1.0 / math.sqrt(hd + rhd)
+    scale = cfg.mla_softmax_scale
     out = blocked_attention(q, k, v, causal=cfg.causal, scale=scale)
     return qdot(out.reshape(b, s, h * vhd), p["wo"])
 
@@ -232,7 +233,7 @@ def mla_decode(p, x, cfg: ModelConfig, cache: dict, cur_pos):
     q_eff = jnp.concatenate([q_lat, q_rope[:, 0].astype(jnp.float32)], axis=-1)
     k_eff = jnp.concatenate([new_c.astype(jnp.float32),
                              new_kr.astype(jnp.float32)], axis=-1)  # (B,S,r+rhd)
-    scale = 1.0 / math.sqrt(hd + rhd)
+    scale = cfg.mla_softmax_scale
     s_ = jnp.einsum("bhr,bsr->bhs", q_eff, k_eff) * scale
     valid = (slot_pos >= 0) & (slot_pos <= cur_pos)
     s_ = jnp.where(valid[None, None, :], s_, NEG_INF)

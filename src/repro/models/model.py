@@ -343,6 +343,11 @@ def _block_decode_multi_paged(kind: str, p: dict, x, cfg: ModelConfig,
     return x, c
 
 
+def _only_layer(stack):
+    """A one-layer segment's parameters without their layer axis."""
+    return jax.tree.map(lambda a: a[0], stack)
+
+
 def _run_segment(seg: Segment, block, x, stack, pools, states):
     """Run one segment's paged blocks: ``block(kind, p, x, pool, state,
     layer) -> (x, pool, state)`` for every kind of every repetition.
@@ -353,28 +358,35 @@ def _run_segment(seg: Segment, block, x, stack, pools, states):
     place.  (As scan inputs and outputs the pools would be re-stacked
     into a second buffer — twice the pool's bytes live at once, and the
     whole pool copied every step.)  Per-slot recurrent ``states`` are
-    small and stay scan inputs/outputs.  Returns ``(x, pools, states)``;
-    ``states`` is None in and out for stateless calls."""
+    small and stay scan inputs/outputs.  Returns ``(x, pools, states,
+    hits)``; ``states`` is None in and out for stateless calls, and
+    ``hits`` stacks the ``moe.expert_hits`` of the segment's expert-share
+    layers (None where it has none)."""
     kinds = seg.kinds
     no_state = ({},) * len(kinds)
 
     def seg_step(carry, ps, ss, li):
         xc, cs = carry
         new_cs, new_ss = [], []
-        for kind, p, c, s in zip(kinds, ps, cs, no_state if ss is None
-                                 else ss):
-            xc, nc, ns = block(kind, p, xc, c, s, li)
-            new_cs.append(nc)
-            new_ss.append(ns)
-        return (xc, tuple(new_cs)), (None if ss is None else tuple(new_ss))
+        with moe_lib.expert_hits() as hits:
+            for kind, p, c, s in zip(kinds, ps, cs, no_state if ss is None
+                                     else ss):
+                xc, nc, ns = block(kind, p, xc, c, s, li)
+                new_cs.append(nc)
+                new_ss.append(ns)
+        return (xc, tuple(new_cs)), (None if ss is None else tuple(new_ss),
+                                     jnp.stack(hits) if hits else None)
 
     if seg.reps == 1:
-        (x, pools), states = seg_step((x, pools), stack, states, None)
-        return x, pools, states
-    (x, pools), states = jax.lax.scan(
+        (x, pools), (states, hits) = seg_step((x, pools), _only_layer(stack),
+                                              states, None)
+        return x, pools, states, hits
+    (x, pools), (states, hits) = jax.lax.scan(
         lambda carry, xs: seg_step(carry, *xs), (x, pools),
         (stack, states, jnp.arange(seg.reps, dtype=jnp.int32)))
-    return x, pools, states
+    if hits is not None:                 # (reps, kinds, T, E) -> layers
+        hits = hits.reshape((-1,) + hits.shape[2:])
+    return x, pools, states, hits
 
 
 def _block_decode(kind: str, p: dict, x, cfg: ModelConfig, window, cache,
@@ -439,8 +451,11 @@ class Model:
             kinds_params = []
             for ki, kind in enumerate(seg.kinds):
                 kk = jax.random.fold_in(k, ki)
+                # every segment's parameters carry a leading layer axis,
+                # a one-layer segment's too (it runs unscanned)
                 if seg.reps == 1:
-                    kinds_params.append(_init_block(kind, kk, cfg))
+                    kinds_params.append(jax.tree.map(
+                        lambda a: a[None], _init_block(kind, kk, cfg)))
                 else:
                     kinds_params.append(jax.vmap(
                         lambda kkk: _init_block(kind, kkk, cfg))(
@@ -503,7 +518,7 @@ class Model:
                 seg_step = jax.checkpoint(seg_step)
 
             if seg.reps == 1:
-                x = seg_step(x, stack)
+                x = seg_step(x, _only_layer(stack))
             else:
                 x, _ = jax.lax.scan(lambda c, ps: (seg_step(c, ps), None),
                                     x, stack)
@@ -696,9 +711,9 @@ class Model:
                     kind, p, xc, cfg, seg.window, c, tbl, start, valid,
                     self.moe_impl, state=s, slot_idx=slot_idx, layer=li)
 
-            x, nc, ns = _run_segment(seg, block, x, params["stacks"][si],
-                                     pools[si],
-                                     None if states is None else states[si])
+            x, nc, ns, _ = _run_segment(
+                seg, block, x, params["stacks"][si], pools[si],
+                None if states is None else states[si])
             new_pools.append(nc)
             if states is not None:
                 new_states.append(ns)
@@ -709,7 +724,8 @@ class Model:
                           pos: jnp.ndarray, valid: jnp.ndarray | None = None,
                           *, states: list | None = None,
                           ring_table: jnp.ndarray | None = None,
-                          state_ok: jnp.ndarray | None = None):
+                          state_ok: jnp.ndarray | None = None,
+                          expert_hits: bool = False):
         """One continuous-batching decode step over the slot batch.
 
         tokens: (B,) int32 (one per slot); pos: (B,) int32 per-slot ragged
@@ -721,7 +737,10 @@ class Model:
         (the sliding-window segments' own page space), and ``state_ok``
         (B,) bool marking slots actually decoding (their state rows
         commit; all other rows keep their value).  The return gains a
-        third element, the updated states.
+        third element, the updated states.  With ``expert_hits`` it gains a
+        last one: the ``(MoE layers, B, held experts)`` bool matrix of the
+        rows each held expert took (``moe.expert_hits``), or None for a
+        model without an expert share.
 
         Multi-token form (speculative verify / prompt scoring): tokens
         (B, C) int32 of C *already-chosen* tokens per slot starting at
@@ -746,7 +765,7 @@ class Model:
                 f"engine threads them automatically)")
         x = params["embed"][tokens]
         x = shard_hint(x, "act_bd")
-        new_pools = []
+        new_pools, hits = [], []
         new_states = [] if states is not None else None
         for si, seg in enumerate(self.plan):
             tbl = (ring_table if (seg.window is not None
@@ -757,16 +776,21 @@ class Model:
                     kind, p, xc, cfg, seg.window, c, tbl, pos, self.moe_impl,
                     state=s, state_ok=state_ok, layer=li)
 
-            x, nc, ns = _run_segment(seg, block, x, params["stacks"][si],
-                                     pools[si],
-                                     None if states is None else states[si])
+            x, nc, ns, h = _run_segment(
+                seg, block, x, params["stacks"][si], pools[si],
+                None if states is None else states[si])
             new_pools.append(nc)
             if states is not None:
                 new_states.append(ns)
+            if h is not None:
+                hits.append(h)
         logits = self._head(params, x[:, None, :])[:, 0]
-        if states is None:
-            return logits, new_pools
-        return logits, new_pools, new_states
+        out = (logits, new_pools)
+        if states is not None:
+            out += (new_states,)
+        if expert_hits:
+            out += (jnp.concatenate(hits) if hits else None,)
+        return out
 
     def _decode_multi_paged(self, params: dict, tokens: jnp.ndarray,
                             pools: list, page_table: jnp.ndarray,
@@ -818,8 +842,8 @@ class Model:
                     valid, self.moe_impl, layer=li)
                 return xc, nc, s
 
-            x, nc, _ = _run_segment(seg, block, x, params["stacks"][si],
-                                    pools[si], None)
+            x, nc, _, _ = _run_segment(seg, block, x, params["stacks"][si],
+                                       pools[si], None)
             new_pools.append(nc)
         logits = self._head(params, x)                     # (B, C, V)
         return logits, new_pools
@@ -843,7 +867,7 @@ class Model:
                 return xc, tuple(new_cs)
 
             if seg.reps == 1:
-                x, nc = seg_step(x, (stack, cache[si]))
+                x, nc = seg_step(x, (_only_layer(stack), cache[si]))
             else:
                 x, nc = jax.lax.scan(seg_step, x, (stack, cache[si]))
             new_caches.append(nc)
@@ -872,7 +896,7 @@ class Model:
                 return xc, tuple(new_cs)
 
             if seg.reps == 1:
-                x, nc = seg_step(x, (stack, cache[si]))
+                x, nc = seg_step(x, (_only_layer(stack), cache[si]))
             else:
                 x, nc = jax.lax.scan(seg_step, x, (stack, cache[si]))
             new_caches.append(nc)

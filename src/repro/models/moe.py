@@ -1,6 +1,10 @@
 """Mixture-of-Experts layer (Llama4-Maverick, DeepSeek-V2 style).
 
-Two execution strategies, selected by token count:
+A config that holds only a share of the routed experts
+(``n_experts_held``: one device's cut of an expert-parallel deployment)
+runs ``moe_share``: routing over every expert, and the held experts'
+rows computed without a capacity, so no row is ever dropped.  Otherwise
+two execution strategies, selected by token count:
 
   * ``dense`` — every expert processes every token, combined with routing
     weights.  O(E x T) compute: only sane for tiny smoke configs, but it is
@@ -16,8 +20,10 @@ query-unique streaming phase; the capacity path preserves that structure
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -29,9 +35,9 @@ from repro.models.common import ModelConfig, dense_init, split_keys
 
 def init_moe(key, cfg: ModelConfig) -> dict:
     ks = split_keys(key, 5)
-    e, d, f = cfg.n_experts, cfg.d_model, cfg.moe_d_ff or cfg.d_ff
+    e, d, f = cfg.experts_held, cfg.d_model, cfg.moe_d_ff or cfg.d_ff
     p = {
-        "router": dense_init(ks[0], d, e, jnp.float32),
+        "router": dense_init(ks[0], d, cfg.n_experts, jnp.float32),
         "w_gate": jax.vmap(lambda k: dense_init(k, d, f))(
             jax.random.split(ks[1], e)),
         "w_up": jax.vmap(lambda k: dense_init(k, d, f))(
@@ -50,13 +56,61 @@ def init_moe(key, cfg: ModelConfig) -> dict:
     return p
 
 
-def _routing(x2d: jnp.ndarray, router: jnp.ndarray, k: int):
-    """Top-k softmax routing.  Returns (weights (T,k) f32, ids (T,k) i32)."""
+def _routing(x2d: jnp.ndarray, router: jnp.ndarray, k: int,
+             normalize: bool = True):
+    """Top-k softmax routing, the k weights renormalised to sum to one
+    when ``normalize``.  Returns (weights (T,k) f32, ids (T,k) i32)."""
     logits = (x2d.astype(jnp.float32) @ router.astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     w, ids = jax.lax.top_k(probs, k)
-    w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
+    if normalize:
+        w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
     return w, ids
+
+
+class _Hits(threading.local):
+    stack: list = []
+
+
+_hits = _Hits()
+
+
+@contextlib.contextmanager
+def expert_hits():
+    """``with expert_hits() as hits:`` -- every ``moe_share`` traced inside
+    appends its ``(T, held experts)`` bool matrix of the rows routed to
+    each held expert (the share's own counters, read by the caller)."""
+    got: list = []
+    _hits.stack = _hits.stack + [got]
+    try:
+        yield got
+    finally:
+        _hits.stack = _hits.stack[:-1]
+
+
+def moe_share(x: jnp.ndarray, p: dict, cfg: ModelConfig) -> jnp.ndarray:
+    """The held share's routed experts (0 .. held - 1), dropless: the
+    router picks top-k over all ``n_experts``, and each held expert runs
+    over every row with a combine weight of zero where the row did not
+    pick it (so its weights stream once per call, whatever the row
+    count).  Rows routed to experts held elsewhere add nothing here.
+    Shared experts are not included."""
+    b, s, d = x.shape
+    x2d = x.reshape(-1, d)
+    w, ids = _routing(x2d, p["router"], cfg.n_experts_per_token,
+                      cfg.norm_topk_prob)
+    held = jnp.arange(p["w_gate"].shape[0])
+    hit = ids[:, :, None] == held                                 # (T,k,E)
+    if _hits.stack:
+        _hits.stack[-1].append(jnp.any(hit, axis=1))
+    comb = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)    # (T, E)
+    g = jnp.einsum("td,edf->etf", x2d, p["w_gate"])
+    u = jnp.einsum("td,edf->etf", x2d, p["w_up"])
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+    y_all = jnp.einsum("etf,efd->etd", h, p["w_down"],
+                       preferred_element_type=jnp.float32)
+    y = jnp.einsum("te,etd->td", comb, y_all)
+    return y.astype(x.dtype).reshape(b, s, d)
 
 
 def _expert_ffn(xe: jnp.ndarray, p: dict) -> jnp.ndarray:
@@ -71,7 +125,8 @@ def moe_dense(x: jnp.ndarray, p: dict, cfg: ModelConfig) -> jnp.ndarray:
     """Reference: all experts on all tokens (tiny configs only)."""
     b, s, d = x.shape
     x2d = x.reshape(-1, d)
-    w, ids = _routing(x2d, p["router"], cfg.n_experts_per_token)
+    w, ids = _routing(x2d, p["router"], cfg.n_experts_per_token,
+                      cfg.norm_topk_prob)
     g = jnp.einsum("td,edf->tef", x2d, p["w_gate"])
     u = jnp.einsum("td,edf->tef", x2d, p["w_up"])
     h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
@@ -143,7 +198,7 @@ def moe_capacity(x: jnp.ndarray, p: dict, cfg: ModelConfig,
     e, k = cfg.n_experts, cfg.n_experts_per_token
     t = b * s
     x2d = x.reshape(t, d)
-    w, ids = _routing(x2d, p["router"], k)                        # (T,k)
+    w, ids = _routing(x2d, p["router"], k, cfg.norm_topk_prob)    # (T,k)
     cap = max(int(math.ceil(t * k / e * capacity_factor)), 1)
     y = _capacity_core(x2d, w, ids, e, cap, p)
     return y.reshape(b, s, d)
@@ -184,7 +239,7 @@ def moe_ep(x: jnp.ndarray, p: dict, cfg: ModelConfig, mesh, axis: str,
         b, s, d = xl.shape
         t = b * s
         x2d = xl.reshape(t, d)
-        w, ids = _routing(x2d, router, k)
+        w, ids = _routing(x2d, router, k, cfg.norm_topk_prob)
         j = jax.lax.axis_index(axis)
         lo = j * el
         local = (ids >= lo) & (ids < lo + el)
@@ -235,8 +290,9 @@ def _chunked(fn, x: jnp.ndarray) -> jnp.ndarray:
     return jnp.moveaxis(ys, 0, 1).reshape(b, s, d)
 
 
-def moe_forward(x: jnp.ndarray, p: dict, cfg: ModelConfig,
-                impl: str = "auto") -> jnp.ndarray:
+def _routed(x: jnp.ndarray, p: dict, cfg: ModelConfig,
+            impl: str) -> jnp.ndarray:
+    """The routed experts' part of the layer, by ``impl``."""
     from repro.parallel import hints
 
     b, s, d = x.shape
@@ -259,11 +315,20 @@ def moe_forward(x: jnp.ndarray, p: dict, cfg: ModelConfig,
                 "inside an already-manual region (e.g. the TP serve "
                 "shard_map, where experts run replicated); use impl='auto'")
         mesh, axis = ep
-        y = _chunked(lambda xc: moe_ep(xc, p, cfg, mesh, axis), x)
-    elif impl == "dense":
-        y = moe_dense(x, p, cfg)
+        return _chunked(lambda xc: moe_ep(xc, p, cfg, mesh, axis), x)
+    if impl == "dense":
+        return moe_dense(x, p, cfg)
+    return _chunked(lambda xc: moe_capacity(xc, p, cfg), x)
+
+
+def moe_forward(x: jnp.ndarray, p: dict, cfg: ModelConfig,
+                impl: str = "auto") -> jnp.ndarray:
+    """Routed plus shared experts.  A held share (``n_experts_held``)
+    always runs ``moe_share``, whatever ``impl`` asks for."""
+    if cfg.n_experts_held:
+        y = moe_share(x, p, cfg)
     else:
-        y = _chunked(lambda xc: moe_capacity(xc, p, cfg), x)
+        y = _routed(x, p, cfg, impl)
     if cfg.n_shared_experts:
         y = y + common.swiglu(x, p["shared"]["w_gate"], p["shared"]["w_up"],
                               p["shared"]["w_down"])

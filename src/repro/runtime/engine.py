@@ -703,6 +703,7 @@ class ContinuousServeEngine:
                    page_table, ring_table, state_ok, temp, topk, topp, minp,
                    seed, rep, bias_ids, bias_vals):
         lay = self._layout
+        hits = None          # (MoE layers, B, held experts) of a share
         if lay.has_state:
             logits, pools, states = self._paged_decode_state(
                 params, tokens, pools, page_table, pos, states, ring_table,
@@ -710,6 +711,9 @@ class ContinuousServeEngine:
         elif lay.has_ring:
             logits, pools = self._paged_decode_ring(
                 params, tokens, pools, page_table, pos, ring_table)
+        elif self.mesh is None:
+            logits, pools, hits = self._paged_decode(
+                params, tokens, pools, page_table, pos, expert_hits=True)
         else:
             logits, pools = self._paged_decode(params, tokens, pools,
                                                page_table, pos)
@@ -724,7 +728,7 @@ class ContinuousServeEngine:
         # step's repetition penalty (rows of inactive slots accumulate
         # garbage harmlessly — admission re-uploads the host mirror)
         presence = presence.at[jnp.arange(nxt.shape[0]), nxt].set(True)
-        return nxt, lp, pools, states, presence
+        return nxt, lp, pools, states, presence, hits
 
     def _chunk_impl(self, params, pools, states, presence, tokens, page_table,
                     ring_table, slot_idx, start, valid, temp, topk, topp,
@@ -1044,13 +1048,13 @@ class ContinuousServeEngine:
 
     def _decode_walks(self) -> dict:
         """Window -> pages the paged decode kernel's walk folds at a time,
-        for each window of the K/V-paged segments (one entry for a model
-        whose layers share a window), from the pools as a shard holds
-        them."""
+        for each window of the K/V- or latent-paged segments (one entry for
+        a model whose layers share a window), from the pools as a shard
+        holds them."""
         walks = {}
         for seg, kinds in zip(self._pool_model.plan, self._pools):
             for pool in kinds:
-                k = pool.get("k")
+                k = pool.get("k", pool.get("latent"))
                 if k is None or seg.window in walks:
                     continue
                 walks[seg.window] = pages_per_step(
@@ -1458,26 +1462,25 @@ class ContinuousServeEngine:
             ring_step = None if rtab is None else np.zeros_like(rtab)
             state_ok = (np.zeros((self.num_slots,), np.bool_)
                         if self._layout.has_state else None)
+            rows = [req.slot for req in decoding]
             for req in decoding:
                 tokens[req.slot] = req.tokens[-1]
                 pos[req.slot] = req.pos
-                step_table[req.slot] = self.cache.table()[req.slot]
-                if ring_step is not None:
-                    ring_step[req.slot] = rtab[req.slot]
-                if state_ok is not None:
-                    # non-decoding slots run the step too (fixed batch)
-                    # but must not commit their garbage recurrent-state
-                    # update
-                    state_ok[req.slot] = True
+            step_table[rows] = self.cache.table()[rows]
+            if ring_step is not None:
+                ring_step[rows] = rtab[rows]
+            if state_ok is not None:
+                # non-decoding slots run the step too (fixed batch) but
+                # must not commit their garbage recurrent-state update
+                state_ok[rows] = True
             if self._presence_dirty:   # admissions/releases since last step
                 self._presence = self._presence_to_device(self._presence_np)
                 self._presence_dirty = False
-            args = (jnp.asarray(tokens), jnp.asarray(pos),
-                    jnp.asarray(step_table))
+            args = tuple(jax.device_put((tokens, pos, step_table)))
             if self.spec is None:
                 # one layer's pages the decode kernel reads (and walks, in
                 # whole chunks), per window of the model's layers
-                at = pos[[req.slot for req in decoding]]
+                at = pos[rows]
                 for window, ppb in self._kv_walks.items():
                     _, live, chunks = live_walk(at, self.page_size, window,
                                                 ppb)
@@ -1492,12 +1495,17 @@ class ContinuousServeEngine:
         if self.spec is not None:
             return self._spec_window(decoding, *args, sargs, outs)
         with span("engine.decode.dispatch"):
-            nxt, lp, self._pools, self._states, self._presence = \
+            nxt, lp, self._pools, self._states, self._presence, hits = \
                 self._step_fn(self.params, self._pools, self._states,
                               self._presence, *args, *sargs)
         with span("engine.decode.wait"):
             nxt = np.asarray(nxt)                      # device sync
             lp = np.asarray(lp)
+            if hits is not None:
+                # the decoding slots' rows each held expert took, per layer
+                h = np.asarray(hits)[:, rows]
+                rec.moe_rows_held = int(h.sum())
+                rec.moe_experts_active = int(h.any(axis=1).sum())
         with span("engine.decode.commit"):
             for req in decoding:
                 if sched.running.get(req.slot) is not req:

@@ -90,6 +90,10 @@ class PageAllocator:
     def pages_of(self, owner) -> list[int]:
         return list(self._owned.get(owner, ()))
 
+    def count_of(self, owner) -> int:
+        """``len(pages_of(owner))`` without the copy."""
+        return len(self._owned.get(owner, ()))
+
     def refcount(self, page: int) -> int:
         return self._rc.get(page, 0)
 
@@ -245,7 +249,7 @@ class PagedKVCache:
         return self._table
 
     def blocks_of(self, slot: int) -> int:
-        return len(self.allocator.pages_of(("slot", slot)))
+        return self.allocator.count_of(("slot", slot))
 
     def chain(self, slot: int, n_tokens: int) -> list[int]:
         """The page ids backing ``slot``'s first ``n_tokens`` positions, in
@@ -309,18 +313,22 @@ class PagedKVCache:
         return added
 
     def _reclaim(self, n: int) -> int:
-        """Drop up to ``n`` LRU index entries whose page would free."""
-        freed = 0
-        for key in list(self._prefix):
-            if freed >= n:
+        """Drop up to ``n`` LRU index entries whose page would free.
+
+        The walk stops at the ``n``-th index-only page: a full pool
+        reclaims a page or two every decode step, from an index that can
+        hold every page of the pool."""
+        victims = []
+        for key, page in self._prefix.items():
+            if len(victims) >= n:
                 break
-            page = self._prefix[key]
             if self.allocator.refcount(page) == 1:     # index-only page
-                del self._prefix[key]
-                del self._prefix_pages[page]
-                self.allocator.drop_page(PREFIX_OWNER, page)
-                freed += 1
-        return freed
+                victims.append((key, page))
+        for key, page in victims:
+            del self._prefix[key]
+            del self._prefix_pages[page]
+            self.allocator.drop_page(PREFIX_OWNER, page)
+        return len(victims)
 
     def _alloc_with_reclaim(self, owner, n: int) -> list[int] | None:
         short = n - self.allocator.num_free

@@ -15,8 +15,9 @@ it).  Each span does two things:
 
 The record also holds what the step did: decode slots run, prefill rows
 and prompt tokens computed, requests admitted, finished and preempted,
-copy-on-write page copies, pages live after it, and the K/V pages the
-decode kernel read and walked.  Records go to a
+copy-on-write page copies, pages live after it, the K/V (or latent)
+pages the decode kernel read and walked, and the expert share's rows and
+active experts.  Records go to a
 bounded :class:`StepLog` (``MAX_RECORDS``, the oldest dropped first) that
 ``step_log()`` on the engines drains.  All of it is always on, with no
 switch: a step's spans cost some 10 us of host time.
@@ -59,6 +60,11 @@ class StepRecord:
     # those pages rounded up to whole chunks
     kv_pages_live: int = 0
     kv_pages_walked: int = 0
+    # a held expert share's decode step, summed over its MoE layers: the
+    # decoding slots' rows routed to held experts, and the held experts
+    # that took at least one of them
+    moe_rows_held: int = 0
+    moe_experts_active: int = 0
 
     @property
     def ns(self) -> int:

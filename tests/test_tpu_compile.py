@@ -22,6 +22,8 @@ from jax.sharding import SingleDeviceSharding
 
 import repro.models  # noqa: F401  (import order: models before kernels.ref)
 from repro.kernels.decode_attention.paged_kernel import paged_decode_attention
+from repro.kernels.decode_attention.paged_mla_kernel import (
+    latent_lanes, paged_mla_decode)
 from repro.kernels.mxfp4_vmm.kernel import mxfp4_vmm
 from repro.quant.formats import MX_BLOCK
 
@@ -127,6 +129,28 @@ def test_paged_decode_fp8_scales_compiles(one_chip):
         s((SLOTS, 32, 96), jnp.bfloat16), pool, pool,
         s((SLOTS, N_BLOCKS), jnp.int32), s((SLOTS,), jnp.int32),
         scales, scales)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+def test_paged_mla_decode_compiles(one_chip, stacked):
+    """DeepSeek-V2-Lite's latent decode: 16 absorbed heads of 512 latent +
+    64 rope lanes (640 with the pad) over bf16 latent pages of 16 tokens,
+    32 slots of up to 1,024 blocks; one layer's pool (the dense layer) and
+    the 26 MoE layers' stacked pool."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    slots, n_blocks, n_pages, width = 32, 1024, 12000, latent_lanes(512, 64)
+    pool = s(((26,) if stacked else ()) + (n_pages, PAGE, width),
+             jnp.bfloat16)
+
+    def fn(q, lat, t, p, layer):
+        return paged_mla_decode(q, lat, t, p, rank=512, scale=0.1147,
+                                layer=layer if stacked else None)
+
+    text = _compiled_text(
+        fn, s((slots, 16, width), jnp.float32), pool,
+        s((slots, n_blocks), jnp.int32), s((slots,), jnp.int32),
+        s((), jnp.int32))
     assert "tpu_custom_call" in text
 
 
